@@ -36,6 +36,9 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     failed = []
     for name, module in MODULES:

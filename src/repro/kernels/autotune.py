@@ -8,8 +8,10 @@ tensors of the right geometry, time each, and cache the winner:
 
   * in-memory (process lifetime), and
   * as JSON on disk (``ICQ_AUTOTUNE_CACHE``, default
-    ``~/.cache/icq_autotune.json``) so ``benchmarks/run.py`` and the
-    serving engine reuse winners across processes.
+    ``.icq_autotune.json`` at the root of the checkout, which git
+    ignores) so ``benchmarks/run.py`` and the serving engine reuse
+    winners across processes, and no file outside the checkout steers
+    block choices.
 
 ``lookup(key)`` is cheap and is what ``backend.prepare`` consults; a
 miss falls back to the static defaults, so autotuning is always
@@ -58,32 +60,27 @@ def register_prefill_m(m: int) -> None:
         PREFILL_MS = tuple(sorted((*PREFILL_MS, m)))
 
 
+#: default cache file: the root of the checkout this module lives in
+DEFAULT_CACHE = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), os.pardir, os.pardir,
+    os.pardir, ".icq_autotune.json")
+
+
 def cache_path() -> str:
-    return os.environ.get(
-        "ICQ_AUTOTUNE_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "icq_autotune.json"),
-    )
+    return os.environ.get("ICQ_AUTOTUNE_CACHE",
+                          os.path.normpath(DEFAULT_CACHE))
 
 
-def _key_suffix(fmt: str, onehot: Optional[str],
-                accum: Optional[str] = "f32") -> str:
-    """Key qualifiers: runtime formats, one-hot dtypes and accumulator
-    dtypes tune independently (v1/f32 keep the legacy un-suffixed
-    spellings so existing cache files stay valid). The one-hot and
-    accumulator dtypes must be part of the key because VMEM admission
-    depends on them — a block winner admitted under a half-width bf16
-    temporary may bust the budget when replayed at f32."""
-    if onehot is None:
-        from repro.kernels.platform import default_onehot_dtype
-
-        onehot = default_onehot_dtype()
+def _key_suffix(fmt: str, accum: Optional[str] = "f32") -> str:
+    """Key qualifiers: runtime formats and accumulator dtypes tune
+    independently (v1/f32 keep the un-suffixed spellings). The
+    accumulator dtype must be part of the key because VMEM admission
+    depends on it."""
     if accum is None:
         from repro.kernels.platform import default_accum_dtype
 
         accum = default_accum_dtype()
     sfx = "" if fmt == "v1" else f"_{fmt}"
-    if onehot != "f32":
-        sfx += f"_oh-{onehot}"
     if accum != "f32":
         sfx += f"_acc-{accum}"
     return sfx
@@ -91,20 +88,18 @@ def _key_suffix(fmt: str, onehot: Optional[str],
 
 def matmul_key(M: int, d_out: int, d_in: int, n_bits: int,
                backend: str, interpret: bool, fmt: str = "v1",
-               onehot: Optional[str] = None,
                accum: Optional[str] = None) -> str:
-    """Cache key (see _key_suffix for the fmt/onehot/accum qualifiers)."""
+    """Cache key (see _key_suffix for the fmt/accum qualifiers)."""
     mode = f"{backend}{'-int' if interpret else ''}"
     return (f"matmul/m{M}_o{d_out}_i{d_in}_n{n_bits}_{mode}"
-            f"{_key_suffix(fmt, onehot, accum)}")
+            f"{_key_suffix(fmt, accum)}")
 
 
 def dequant_key(d_out: int, d_in: int, n_bits: int,
-                backend: str, interpret: bool, fmt: str = "v1",
-                onehot: Optional[str] = None) -> str:
+                backend: str, interpret: bool, fmt: str = "v1") -> str:
     mode = f"{backend}{'-int' if interpret else ''}"
     return (f"dequant/o{d_out}_i{d_in}_n{n_bits}_{mode}"
-            f"{_key_suffix(fmt, onehot)}")
+            f"{_key_suffix(fmt)}")
 
 
 def _load_disk() -> None:
@@ -150,49 +145,32 @@ def reset(forget_disk: bool = True) -> None:
     _LOADED_FROM = None if forget_disk else cache_path()
 
 
-def _synthetic_runtime(d_out: int, d_in: int, n_bits: int, seed: int = 0):
-    """Random tensors with the exact runtime-format geometry (timing only)."""
+def _synthetic_packed(d_out: int, d_in: int, n_bits: int,
+                      gamma: float = 0.05, seed: int = 0):
+    """Random ICQPacked of the right geometry (timing only): random codes
+    and codebooks, sorted uniform outlier positions for the gap stream."""
     import jax.numpy as jnp
 
-    from repro.core.packing import packed_width
-
-    rng = np.random.default_rng(seed)
-    wc, wb = packed_width(d_in, n_bits), packed_width(d_in, 1)
-    C = 2 << n_bits
-    codes = jnp.asarray(
-        rng.integers(0, 2**32, size=(d_out, wc), dtype=np.uint32))
-    bitmap = jnp.asarray(
-        rng.integers(0, 2**32, size=(d_out, wb), dtype=np.uint32))
-    codebooks = jnp.asarray(rng.standard_normal((d_out, C)), jnp.float32)
-    return codes, bitmap, codebooks
-
-
-def _synthetic_stream(d_out: int, d_in: int, gamma: float = 0.05,
-                      seed: int = 0):
-    """Plausible gap stream (sorted uniform outlier positions) for timing
-    the v2 kernels: returns an encoded GapStream of the right geometry."""
     from repro.core.bounds import optimal_b
+    from repro.core.icquant import ICQPacked
     from repro.core.index_coding import encode_positions
+    from repro.core.packing import packed_width
 
     rng = np.random.default_rng(seed)
     p = max(1, int(gamma * d_in))
     pos = np.sort(
         rng.random((d_out, d_in)).argpartition(p, axis=1)[:, :p], axis=1)
-    return encode_positions(pos, d_in, optimal_b(gamma))
-
-
-def _v2_sidecar(stream, tile: int, pk: int):
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core.index_coding import stream_checkpoints
-    from repro.core.packing import pack_symbols_np
-
-    sym = np.asarray(jax.device_get(stream.symbols))
-    cnt = np.asarray(jax.device_get(stream.counts))
-    offs, dbase = stream_checkpoints(sym, cnt, stream.b, tile, pk)
-    return (jnp.asarray(pack_symbols_np(sym, stream.b)),
-            jnp.asarray(offs), jnp.asarray(dbase))
+    b = optimal_b(gamma)
+    stream = encode_positions(pos, d_in, b)
+    codes = rng.integers(0, 2**32, size=(d_out, packed_width(d_in, n_bits)),
+                         dtype=np.uint32)
+    return ICQPacked(
+        codes=jnp.asarray(codes), symbols=stream.symbols,
+        counts=stream.counts,
+        codebooks=jnp.asarray(
+            rng.standard_normal((d_out, 2, 1 << n_bits)), jnp.float32),
+        n_bits=n_bits, b=b, gamma=gamma, d_out=d_out, d_in=d_in,
+        method="rtn")
 
 
 def _time_once(fn, iters: int) -> float:
@@ -207,6 +185,27 @@ def _time_once(fn, iters: int) -> float:
     return float(np.median(times)) * 1e6
 
 
+def _sweep(pk, candidates, run, *, interpret: bool, fmt: str, iters: int):
+    """Time ``run(prep)`` for each distinct prepared blocking of the
+    candidates (snapped and clamped by ``prepare``); return the fastest
+    blocking and its median microseconds."""
+    from repro.kernels import backend as _backend
+
+    best, best_us = None, float("inf")
+    seen = set()
+    for cand in candidates:
+        prep = _backend.prepare(pk, blocks=cand, backend="pallas",
+                                interpret=interpret, fmt=fmt)
+        resolved = (prep.block_m, prep.block_n, prep.block_k)
+        if resolved in seen:                         # snapping may collide
+            continue
+        seen.add(resolved)
+        us = _time_once(lambda: run(prep), iters)
+        if us < best_us:
+            best, best_us = resolved, us
+    return best, best_us
+
+
 def autotune_matmul(
     M: int, d_out: int, d_in: int, n_bits: int,
     *,
@@ -218,21 +217,16 @@ def autotune_matmul(
     """Sweep fused-matmul blocks; cache and return the winner.
 
     ``fmt`` selects the runtime format being tuned (independent cache
-    entries — v2 kernels have different VMEM/decode trade-offs).
-    Candidates whose VMEM estimate exceeds the budget are skipped before
-    ever reaching the compiler; if every candidate busts the budget the
-    most-clamped one still runs so a winner always exists.
+    entries — v2 kernels have different VMEM/decode trade-offs). Each
+    candidate is snapped to the kernel layout and clamped to the VMEM
+    budget by ``backend.prepare``; candidates that collapse onto an
+    already-timed blocking are skipped.
 
     Returns {"blocks": (bm, bn, bk), "us": median_us, "cached": bool}.
     """
     import jax.numpy as jnp
 
-    from repro.core.packing import symbol_cols
     from repro.kernels import backend as _backend
-    from repro.kernels.icq_matmul import (
-        icq_matmul, icq_matmul_v2, matmul_blocks,
-    )
-    from repro.kernels.icq_dequant import _round_up
     from repro.kernels.platform import default_interpret
 
     if interpret is None:
@@ -242,53 +236,20 @@ def autotune_matmul(
     if hit is not None:
         return dict(blocks=tuple(hit), us=None, cached=True)
 
-    codes, bitmap, codebooks = _synthetic_runtime(d_out, d_in, n_bits)
+    pk = _synthetic_packed(d_out, d_in, n_bits)
     x = jnp.asarray(
         np.random.default_rng(1).standard_normal((M, d_in)), jnp.float32)
-    stream = _synthetic_stream(d_out, d_in) if fmt == "v2" else None
-    s_cols = symbol_cols(
-        max(-(-stream.symbols.shape[-1] // (32 // stream.b)), 1), stream.b
-    ) if fmt == "v2" else 0
-    C = 2 << n_bits
 
-    best, best_us = None, float("inf")
-    seen = set()
-    budget = _backend.vmem_budget_bytes()
-    for bm, bn, bk in (candidates or MATMUL_CANDIDATES):
-        resolved = matmul_blocks(M, d_out, d_in, n_bits, bm, bn, bk, fmt=fmt)
-        if resolved in seen:                        # clamping may collide
-            continue
-        if _backend.vmem_bytes_estimate(
-                *resolved, n_bits=n_bits, C=C, fmt=fmt,
-                s_cols=s_cols) > budget:
-            continue                                # would bust VMEM
-        seen.add(resolved)
-        if fmt == "v2":
-            tile = resolved[2]
-            pk = _round_up(d_in, tile)
-            syms, offs, dbase = _v2_sidecar(stream, tile, pk)
-            fn = lambda bm=bm, bn=bn, t=tile, s=syms, o=offs, d=dbase: \
-                icq_matmul_v2(
-                    x, codes, s, o, d, codebooks, n_bits=n_bits,
-                    b=stream.b, d_in=d_in, tile=t, block_m=bm, block_n=bn,
-                    interpret=interpret,
-                )
-        else:
-            fn = lambda bm=bm, bn=bn, bk=bk: icq_matmul(
-                x, codes, bitmap, codebooks, n_bits=n_bits, d_in=d_in,
-                block_m=bm, block_n=bn, block_k=bk, interpret=interpret,
-            )
-        us = _time_once(fn, iters)
-        if us < best_us:
-            best, best_us = (bm, bn, bk), us
-    if best is None:  # every candidate over budget: run the clamped floor
-        bm, bn, bk = _backend._clamp_blocks_to_budget(
-            *matmul_blocks(M, d_out, d_in, n_bits, *MATMUL_CANDIDATES[0],
-                           fmt=fmt),
-            n_bits=n_bits, C=C, fmt=fmt, d_in=d_in, s_cols=s_cols)
-        best, best_us = (bm, bn, bk), None
+    def run(prep):
+        bm = min(prep.block_m, _backend._round_up(M, 8))
+        x_p = jnp.pad(x, ((0, _backend._round_up(M, bm) - M),
+                          (0, prep.pk - d_in)))
+        return _backend._fused(x_p, prep, bm, prep.block_n, prep.block_k)
+
+    best, us = _sweep(pk, candidates or MATMUL_CANDIDATES, run,
+                      interpret=interpret, fmt=fmt, iters=iters)
     record(key, best)
-    return dict(blocks=best, us=best_us, cached=False)
+    return dict(blocks=best, us=us, cached=False)
 
 
 def autotune_dequant(
@@ -299,11 +260,8 @@ def autotune_dequant(
     iters: int = 3,
     fmt: str = "v1",
 ) -> Dict[str, object]:
-    """Sweep dequant blocks; cache and return the winner."""
+    """Sweep dequant (block_n, block_k); cache and return the winner."""
     from repro.kernels import backend as _backend
-    from repro.kernels.icq_dequant import (
-        _round_up, dequant_blocks, icq_dequant, icq_dequant_v2,
-    )
     from repro.kernels.platform import default_interpret
 
     if interpret is None:
@@ -313,52 +271,17 @@ def autotune_dequant(
     if hit is not None:
         return dict(blocks=tuple(hit), us=None, cached=True)
 
-    from repro.core.packing import symbol_cols
+    pk = _synthetic_packed(d_out, d_in, n_bits)
 
-    codes, bitmap, codebooks = _synthetic_runtime(d_out, d_in, n_bits)
-    stream = _synthetic_stream(d_out, d_in) if fmt == "v2" else None
-    s_cols = symbol_cols(
-        max(-(-stream.symbols.shape[-1] // (32 // stream.b)), 1), stream.b
-    ) if fmt == "v2" else 0
-    best, best_us = None, float("inf")
-    seen = set()
-    budget = _backend.vmem_budget_bytes()
-    C = 2 << n_bits
-    for br, bc in (candidates or DEQUANT_CANDIDATES):
-        resolved = dequant_blocks(d_out, d_in, n_bits, br, bc, fmt=fmt)
-        if resolved in seen:
-            continue
-        if _backend.vmem_bytes_estimate(
-                8, *resolved, n_bits=n_bits, C=C, fmt=fmt,
-                s_cols=s_cols) > budget:
-            continue
-        seen.add(resolved)
-        if fmt == "v2":
-            tile = resolved[1]
-            syms, offs, dbase = _v2_sidecar(
-                stream, tile, _round_up(d_in, tile))
-            fn = lambda br=br, t=tile, s=syms, o=offs, d=dbase: \
-                icq_dequant_v2(
-                    codes, s, o, d, codebooks, n_bits=n_bits, b=stream.b,
-                    d_in=d_in, tile=t, block_r=br, interpret=interpret,
-                )
-        else:
-            fn = lambda br=br, bc=bc: icq_dequant(
-                codes, bitmap, codebooks, n_bits=n_bits, d_in=d_in,
-                block_r=br, block_c=bc, interpret=interpret,
-            )
-        us = _time_once(fn, iters)
-        if us < best_us:
-            best, best_us = (br, bc), us
-    if best is None:  # every candidate over budget: run the clamped floor
-        br, bc = dequant_blocks(d_out, d_in, n_bits,
-                                *DEQUANT_CANDIDATES[-1], fmt=fmt)
-        _, br, bc = _backend._clamp_blocks_to_budget(
-            8, br, bc, n_bits=n_bits, C=C, fmt=fmt, d_in=d_in,
-            s_cols=s_cols)
-        best, best_us = (br, bc), None
+    def run(prep):
+        return _backend._dequant_t(prep, prep.block_n, prep.block_k)
+
+    cands = [(8, bn, bk) for bn, bk in (candidates or DEQUANT_CANDIDATES)]
+    best, us = _sweep(pk, cands, run, interpret=interpret, fmt=fmt,
+                      iters=iters)
+    best = best[1:]
     record(key, best)
-    return dict(blocks=best, us=best_us, cached=False)
+    return dict(blocks=best, us=us, cached=False)
 
 
 def paged_attn_key(G: int, d: int, dv: int, bs: int, n_pt: int, *,
@@ -427,15 +350,15 @@ def autotune_paged_attn(
     rng = np.random.default_rng(0)
     nb = B * n_pt + 1
     k_pool = jnp.asarray(
-        rng.standard_normal((nb, bs, Hkv, d)), jnp.float32)
+        rng.standard_normal((nb, Hkv, bs, d)), jnp.float32)
     v_pool = jnp.asarray(
-        rng.standard_normal((nb, bs, Hkv, dv)), jnp.float32)
+        rng.standard_normal((nb, Hkv, bs, dv)), jnp.float32)
     q = jnp.asarray(rng.standard_normal((B, Hkv, G, d)), jnp.float32)
     q2 = k2_pool = None
     if d2:
         q2 = jnp.asarray(rng.standard_normal((B, Hkv, G, d2)), jnp.float32)
         k2_pool = jnp.asarray(
-            rng.standard_normal((nb, bs, Hkv, d2)), jnp.float32)
+            rng.standard_normal((nb, Hkv, bs, d2)), jnp.float32)
     # full lanes (worst case): every page mapped, shuffled placement
     pages = jnp.asarray(
         rng.permutation(np.arange(1, nb))[:B * n_pt].reshape(B, n_pt)

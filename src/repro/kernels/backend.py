@@ -10,42 +10,38 @@ Prepared layout
 ---------------
 ``prepare()`` converts a storage-format ``ICQPacked`` (or serving-format
 ``ICQRuntime`` / runtime dict) into an ``ICQPrepared`` **once at model
-load time**. The layout is the kernel runtime format, pre-padded and
-pre-blocked so the per-call ``jnp.pad`` + reshape work in the kernel
-wrappers disappears from the hot path.
+load time**: pre-padded, pre-blocked, and transposed so that the lane
+(minor) axis of every tensor is the weight row. Any bit width then tiles
+as whole (8, 128) blocks. pn = d_out padded to the N block (or to 8 when
+one block covers every row); pk = d_in padded to a multiple of 256.
 
-Two runtime formats (``fmt``, default ``platform.default_runtime_fmt()``
+  codes:     (*lead, n * pk / 32, pn) uint32 — n-bit codes as n bit
+             planes (``core/packing.py``: ``pack_planes``), exactly n
+             bits per weight.
+  codebooks: (*lead, 2^(n+1), pn) f32 (or bf16 with
+             ``codebook_dtype='bf16'``) — [inlier ++ outlier] levels;
+             padded rows are zero so they contribute nothing.
+
+Two selector formats (``fmt``, default ``platform.default_runtime_fmt()``
 = 'v2', env override ``ICQ_RUNTIME_FMT=v1|v2``):
 
-  v1 — dense selector bitmap (the PR-1 layout, bitwise-parity fallback):
-  codes:     (*lead, pn, pk // k)  uint32 — k = 32 // n_bits packed
-             codes; rows padded d_out -> pn = round_up(d_out, block_n),
-             columns padded d_in -> pk = round_up(d_in, block_k) where
-             block_k is a multiple of lcm(k, 32) so code words and
-             bitmap words block on the same column tiles.
-  bitmap:    (*lead, pn, pk // 32) uint32 — 1-bit outlier selector
-             (~ +1.0 bit/weight of HBM outlier overhead).
+  v1 — dense selector bitmap (the bitwise-parity fallback):
+  bitmap:    (*lead, pk / 32, pn) uint32 — one bit plane (+1 b/w).
 
   v2 — checkpointed gap stream (the paper-faithful ~0.3 b/w stream,
   served directly; the kernels decode their selector tile in VMEM):
-  syms:      (*lead, pn, SW) uint32 — packed b-bit gap symbols
-             (value-1 encoding, all-ones = escape flag).
-  offs:      (*lead, pn, T+1) uint16 — symbol-stream offset at every
-             block_k boundary (T = pk / block_k; last column is the
+  syms:      (*lead, SW, pn) uint32 — each row's packed b-bit gap
+             symbols down the sublanes (value-1 encoding, all-ones =
+             escape flag).
+  offs:      (*lead, T+1, pn) uint16 — symbol-stream offset at every
+             block_k boundary (T = pk / block_k; last row is the
              per-row symbol count sentinel).
-  dbase:     (*lead, pn, T) uint8 (uint16 if b > 8) — checkpoint base
+  dbase:     (*lead, T, pn) uint8 (uint16 if b > 8) — checkpoint base
              delta: t*block_k - dbase[t] is the absolute position
              consumed before tile t's first symbol.
-             Outlier overhead ~= stream (~0.31-0.38 with word/row
-             padding) + 24/block_k checkpoint bits ~= 0.40-0.45 b/w.
-             block_k IS the checkpoint tile: re-blocking requires
-             re-preparing. v2 column granularity is k alone (no bitmap
-             to 32-align), so n=3 keeps large tiles.
+             block_k IS the checkpoint tile: re-blocking K requires
+             re-preparing.
 
-  Shared:
-  codebooks: (*lead, pn, 2^(n+1)) f32 (or bf16 with
-             ``codebook_dtype='bf16'``) — [inlier ++ outlier] levels;
-             padded rows are zero so they contribute nothing.
   static aux: n_bits, d_out, d_in (true shapes), block_m (cap for the M
              tile), block_n, block_k (exact divisors of pn / pk),
              backend ('pallas' | 'xla'), interpret (bool), fmt
@@ -68,22 +64,19 @@ shape, and platform (see kernels/platform.py):
 
   * backend 'xla' (default off-TPU): prepared-layout XLA reconstruction,
     then a dense matmul — bitwise-identical results to the reference
-    ``dequantize`` path. For v1 that is bitmap unpack + take_along_axis;
-    for v2 the checkpointed stream is decoded in-graph (global cumsum +
-    scatter — exact integer math, so v1/v2/reference agree bit-for-bit;
-    unlike the kernel arms this re-decodes per call, the price of the
-    fallback arm keeping v2's HBM footprint).
+    ``dequantize`` path. For v1 that is a bit-plane unpack; for v2 the
+    stream is decoded in-graph (global cumsum + scatter — exact integer
+    math, so v1/v2/reference agree bit-for-bit).
   * backend 'pallas', M <= ICQ_DECODE_M (decode): the fused
-    ``icq_matmul`` kernel — packed weights go HBM->VMEM, dense bf16
+    ``matmul_padded[_v2]`` kernel — packed weights go HBM->VMEM, dense
     weights never touch HBM.
-  * backend 'pallas', M > ICQ_DECODE_M (prefill): ``icq_dequant`` once,
-    then a dense MXU matmul in the padded space.
+  * backend 'pallas', M > ICQ_DECODE_M (prefill): ``dequant_padded[_v2]``
+    once, then a dense MXU matmul in the padded space.
 
 Block sizes come from the autotune cache (kernels/autotune.py) when a
 winner for this (shape, n_bits, backend, fmt) exists, else static
 defaults; either way candidates are clamped so the kernel's VMEM
-working set (one-hot codebook temporary + accumulator + selector-decode
-temporaries) stays under ``ICQ_VMEM_BUDGET_MB`` (default 16) instead of
+working set stays under ``ICQ_VMEM_BUDGET_MB`` (default 16) instead of
 failing in the compiler. The prepare-time table is keyed on the decode
 shape (M=1); at call time ``arm_blocks`` re-consults the cache for the
 arm the call actually lands on — fused-matmul winners at the bucketed
@@ -109,31 +102,24 @@ from repro.core.index_coding import (
     selector_from_stream_cols,
     stream_checkpoints,
 )
+from repro.core.packing import PLANE_ROWS
 from repro.kernels import autotune
 from repro.kernels.icq_dequant import (
-    SEL_CHUNK,
     _round_up,
-    column_granularity,
     dequant_padded,
     dequant_padded_v2,
-    onehot_itemsize,
-    snap_block_k,
 )
-from repro.kernels.icq_matmul import (
-    matmul_blocks,
-    matmul_padded,
-    matmul_padded_v2,
-)
+from repro.kernels.icq_matmul import matmul_padded, matmul_padded_v2
 from repro.kernels.platform import (
     decode_m_threshold,
     default_accum_dtype,
     default_backend,
     default_interpret,
-    default_onehot_dtype,
     default_runtime_fmt,
 )
 
 DEFAULT_BLOCKS = (128, 128, 512)  # (block_m cap, block_n, block_k)
+LANE = 128                        # N blocks are lane multiples (or all rows)
 
 _CODEBOOK_DTYPES = {None: jnp.float32, "f32": jnp.float32,
                     "bf16": jnp.bfloat16}
@@ -189,25 +175,25 @@ class ICQPrepared:
     v1 carries ``bitmap`` (``syms``/``offs``/``dbase`` are None);
     v2 carries the checkpointed stream (``bitmap`` is None).
 
-    ``sel_memo`` is the pure-XLA arm's decoded-selector memo: a packed
-    1-bit bitmap (v1 layout, unpadded) materialized once at prepare time
-    when the weight will execute on the XLA arm, so per-call graphs
-    unpack it with a shift/mask instead of re-decoding the v2 gap stream
-    in-graph (cumsum + scatter per launch — the chunked-prefill TTFT
-    regression PR 4 measured). The memo is bit-derived from the exact
-    decode it replaces, so XLA-arm outputs are unchanged bitwise. It is
+    ``sel_memo`` is the pure-XLA arm's decoded-selector memo: a 1-bit
+    plane (the v1 bitmap layout) materialized once at prepare time when
+    the weight will execute on the XLA arm, so per-call graphs unpack it
+    with a shift/mask instead of re-decoding the v2 gap stream in-graph
+    (a cumsum + scatter per launch, which slowed chunked-prefill TTFT).
+    The memo is bit-derived from the exact decode it
+    replaces, so XLA-arm outputs are unchanged bitwise. It is
     *excluded* from the bits/weight accounting: it exists only on the
     off-TPU fallback arm (where HBM residency is not the constraint the
     runtime-format numbers are about) and never ships to the Pallas
     kernels. ``ICQ_XLA_SEL_MEMO=0`` disables it.
     """
 
-    codes: jnp.ndarray        # (*lead, pn, pk // k) uint32
-    bitmap: Optional[jnp.ndarray]     # v1: (*lead, pn, pk // 32) uint32
-    codebooks: jnp.ndarray    # (*lead, pn, 2^(n+1)) f32/bf16
-    syms: Optional[jnp.ndarray]       # v2: (*lead, pn, SW) uint32
-    offs: Optional[jnp.ndarray]       # v2: (*lead, pn, T+1) uint16
-    dbase: Optional[jnp.ndarray]      # v2: (*lead, pn, T) uint8/uint16
+    codes: jnp.ndarray        # (*lead, n * pk / 32, pn) uint32
+    bitmap: Optional[jnp.ndarray]     # v1: (*lead, pk / 32, pn) uint32
+    codebooks: jnp.ndarray    # (*lead, 2^(n+1), pn) f32/bf16
+    syms: Optional[jnp.ndarray]       # v2: (*lead, SW, pn) uint32
+    offs: Optional[jnp.ndarray]       # v2: (*lead, T+1, pn) uint16
+    dbase: Optional[jnp.ndarray]      # v2: (*lead, T, pn) uint8/uint16
     n_bits: int = dataclasses.field(metadata=dict(static=True))
     d_out: int = dataclasses.field(metadata=dict(static=True))
     d_in: int = dataclasses.field(metadata=dict(static=True))
@@ -222,7 +208,7 @@ class ICQPrepared:
     # the padded stored bytes — None for v1 (see verify_integrity)
     crc: Optional[Tuple[Tuple[str, int], ...]] = dataclasses.field(
         default=None, metadata=dict(static=True))
-    sel_memo: Optional[jnp.ndarray] = None  # (*lead, d_out, ceil(d_in/32))
+    sel_memo: Optional[jnp.ndarray] = None  # (*lead, pk / 32, pn)
 
     def tree_flatten(self):
         return ((self.codes, self.bitmap, self.codebooks,
@@ -235,6 +221,14 @@ class ICQPrepared:
     def tree_unflatten(cls, aux, children):
         *tensors, sel_memo = children
         return cls(*tensors, *aux, sel_memo=sel_memo)
+
+    @property
+    def pn(self) -> int:
+        return self.codes.shape[-1]
+
+    @property
+    def pk(self) -> int:
+        return self.codes.shape[-2] * 32 // self.n_bits
 
     def verify_integrity(self) -> None:
         """Recompute the v2 sidecar checksums and compare to the crc
@@ -299,6 +293,16 @@ def _pad_last2(x: jnp.ndarray, rows: int, cols: int) -> jnp.ndarray:
     return jnp.pad(x, pad)
 
 
+def _rows_to_lanes(x: jnp.ndarray, pn: int) -> jnp.ndarray:
+    """(*lead, rows, c) row-major tensor -> (*lead, c, pn) kernel layout."""
+    return jnp.swapaxes(_pad_last2(jnp.asarray(x), pn, x.shape[-1]), -1, -2)
+
+
+def _planes(codes2d: jnp.ndarray, n_bits: int, pn: int, pk: int):
+    """(*lead, rows, d_in) codes -> (*lead, n*pk/32, pn) bit planes."""
+    return packing.pack_planes(_pad_last2(codes2d, pn, pk), n_bits)
+
+
 def _as_runtime(w: Union[ICQPacked, ICQRuntime, Dict]) -> ICQRuntime:
     if isinstance(w, ICQPacked):
         return to_runtime_format(w)
@@ -311,8 +315,32 @@ def _as_runtime(w: Union[ICQPacked, ICQRuntime, Dict]) -> ICQRuntime:
 
 
 # ---------------------------------------------------------------------------
-# VMEM budgeting
+# blocks and VMEM budgeting
 # ---------------------------------------------------------------------------
+
+def snap_block_k(d_in: int, block_k: int) -> int:
+    """Largest multiple of 256 <= block_k that divides round_up(d_in, 256)
+    (K padding stays below one 256-row plane group)."""
+    q = _round_up(d_in, PLANE_ROWS) // PLANE_ROWS
+    t_req = min(max(1, block_k // PLANE_ROWS), q)
+    return PLANE_ROWS * max(t for t in range(1, t_req + 1) if q % t == 0)
+
+
+def snap_block_n(d_out: int, block_n: int) -> Tuple[int, int]:
+    """(block_n, pn) for d_out rows: one block of all rows (padded to 8)
+    when they fit in block_n, else a lane multiple dividing pn."""
+    if d_out <= max(block_n, LANE):
+        pn = _round_up(d_out, 8)
+        return pn, pn
+    bn = max(LANE, block_n // LANE * LANE)
+    return bn, _round_up(d_out, bn)
+
+
+def blocks_fit(bn: int, bk: int, pn: int, pk: int) -> bool:
+    """Whether (bn, bk) tiles a prepared (pn, pk) weight legally."""
+    return (bn >= 1 and bk >= 1 and pn % bn == 0 and pk % bk == 0
+            and (bn == pn or bn % LANE == 0) and bk % PLANE_ROWS == 0)
+
 
 def vmem_budget_bytes() -> int:
     """Per-kernel VMEM working-set budget (ICQ_VMEM_BUDGET_MB, default 16)."""
@@ -324,48 +352,41 @@ def vmem_budget_bytes() -> int:
 def vmem_bytes_estimate(block_m: int, block_n: int, block_k: int, *,
                         n_bits: int, C: int, fmt: str = "v1",
                         s_cols: int = 0,
-                        onehot: Optional[str] = None,
                         accum: Optional[str] = None) -> int:
-    """Rough VMEM bytes for one fused-matmul block (dequant is a subset).
-
-    Dominated by the (BN, BK, C) one-hot codebook-select temporary —
-    charged at the ``ICQ_ONEHOT_DTYPE`` width (``onehot`` overrides), so
-    a bf16 one-hot halves the dominant term and lets the autotuner admit
-    larger prefill blocks under the same budget; v2 adds the unpacked
-    symbol stream and the (BN, SEL_CHUNK, BK) selector compare chunk.
-    Deliberately coarse — used to reject/clamp block candidates before
-    the compiler OOMs, not to bill exact bytes."""
+    """Rough VMEM bytes for one fused-matmul grid step (dequant is a
+    subset): double-buffered input and output blocks, the accumulator,
+    and about six (BK, BN) int32/f32 temporaries of the tile rebuild
+    (unpacked codes, selector, select tree). v2 adds its symbol block,
+    the per-symbol decode temporaries and the rel scratch (``s_cols``
+    unpacked symbols per row). Used to reject or clamp block candidates
+    before the compiler does, not to bill exact bytes."""
     f32 = 4
     if accum is None:
         accum = default_accum_dtype()
-    est = block_m * block_k * f32                      # x tile
-    est += block_m * block_n * f32                     # out tile
+    est = 2 * block_m * block_k * f32                  # x tiles
+    est += 2 * block_m * block_n * f32                 # out tiles
     est += block_m * block_n * (2 if accum == "bf16" else 4)  # acc scratch
-    est += block_n * block_k * f32                     # dequantized W tile
-    est += block_n * block_k * C * onehot_itemsize(onehot)  # one-hot temp
-    est += block_n * (block_k // (32 // n_bits)) * 4   # packed codes
+    est += 2 * n_bits * block_k // 32 * block_n * 4    # bit-plane words
+    est += 2 * C * block_n * f32                       # codebooks
+    est += 6 * block_k * block_n * f32                 # tile temporaries
     if fmt == "v2":
-        est += 3 * block_n * s_cols * 4                # syms + pos/rel temps
-        est += block_n * min(SEL_CHUNK, max(s_cols, 1)) * block_k * f32
+        est += 6 * s_cols * block_n * 4               # syms, decode, scratch
     else:
-        est += block_n * (block_k // 32) * 4           # bitmap words
+        est += 2 * block_k // 32 * block_n * 4         # bitmap plane
     return est
 
 
 def _clamp_blocks_to_budget(bm: int, bn: int, bk: int, *, n_bits: int,
                             C: int, fmt: str, d_in: int, s_cols: int,
                             allow_bk: bool = True):
-    """Shrink (bn, bk, bm) until the VMEM estimate fits the budget."""
+    """Shrink (bk, bn, bm) until the VMEM estimate fits the budget."""
     budget = vmem_budget_bytes()
-    lcm = column_granularity(n_bits, fmt)
     while vmem_bytes_estimate(bm, bn, bk, n_bits=n_bits, C=C, fmt=fmt,
                               s_cols=s_cols) > budget:
-        if allow_bk and bk > lcm:
-            nbk = snap_block_k(d_in, lcm, max(lcm, bk // 2))
-            if nbk < bk:
-                bk = nbk
-                continue
-        if bn > 8:
+        if allow_bk and bk > PLANE_ROWS:
+            bk = snap_block_k(d_in, bk // 2)
+            continue
+        if bn > LANE and bn % (2 * LANE) == 0:
             bn //= 2
             continue
         if bm > 8:
@@ -383,8 +404,9 @@ def _encode_v2_sidecar(symbols, counts, b: int, d_out: int, tile: int,
                        total_len: int):
     """Pack the gap stream + build checkpoints, host-side (load time).
 
-    symbols/counts may carry leading stack axes; returns jnp arrays
-    (syms uint32, offs uint16, dbase uint8/16) with those axes restored.
+    symbols/counts may carry leading stack axes; returns row-major jnp
+    arrays (syms uint32, offs uint16, dbase uint8/16) with those axes
+    restored.
     """
     sym_np = np.asarray(jax.device_get(symbols))
     cnt_np = np.asarray(jax.device_get(counts))
@@ -414,8 +436,8 @@ def prepare(
 
     ``blocks`` is (block_m_cap, block_n, block_k); when None the
     autotune cache is consulted (decode-shape key, M=1) and static
-    defaults are used on a miss. Either way blocks are clamped to the
-    VMEM budget.
+    defaults are used on a miss. Either way blocks are snapped to the
+    kernel layout and clamped to the VMEM budget.
 
     ``fmt`` is 'v1' | 'v2' | None (None = platform default, normally
     'v2'). v2 needs the gap stream, so it requires an ``ICQPacked`` (or
@@ -447,7 +469,7 @@ def prepare(
     has_stream = isinstance(w, ICQPacked) or is_v2_dict
     fmt = "v2" if (want == "v2" and has_stream) else "v1"
 
-    # -- source tensors ------------------------------------------------
+    # -- source tensors (row-major storage layout) -----------------------
     bitmap = syms = offs = dbase = None
     b = 0
     if is_v2_dict:
@@ -471,11 +493,8 @@ def prepare(
             1, d_out, d_in, n_bits, "pallas", interpret, fmt=fmt))
         blocks = tuple(hit) if hit is not None else DEFAULT_BLOCKS
     bm_cap, bn, bk = blocks
-    # snap to hardware/packing granularity (M slot resolved per call)
-    _, bn, bk = matmul_blocks(8, d_out, d_in, n_bits, bm_cap, bn, bk,
-                              fmt=fmt)
-    if is_v2_dict:
-        bk = tile_src  # checkpoints were built for this tile
+    bn, pn = snap_block_n(d_out, bn)
+    bk = tile_src if is_v2_dict else snap_block_k(d_in, bk)
     C = codebooks.shape[-1]
     s_cols = 0
     if fmt == "v2":
@@ -485,9 +504,6 @@ def prepare(
     bm_cap, bn, bk = _clamp_blocks_to_budget(
         bm_cap, bn, bk, n_bits=n_bits, C=C, fmt=fmt, d_in=d_in,
         s_cols=s_cols, allow_bk=not is_v2_dict)
-
-    k = 32 // n_bits
-    pn = _round_up(d_out, bn)
     pk = _round_up(d_in, bk)
 
     # -- v2 sidecar -----------------------------------------------------
@@ -495,16 +511,18 @@ def prepare(
         syms, offs, dbase = _encode_v2_sidecar(
             w.symbols, w.counts, b, d_out, tile=bk, total_len=pk)
 
-    def pad_rows(x):
-        return None if x is None else _pad_last2(x, pn, x.shape[-1])
+    def lanes(x):
+        return None if x is None else _rows_to_lanes(x, pn)
 
+    dense_codes = packing.unpack_codes(codes, n_bits, d_in)
     prep = ICQPrepared(
-        codes=_pad_last2(codes, pn, pk // k),
-        bitmap=None if fmt == "v2" else _pad_last2(bitmap, pn, pk // 32),
-        codebooks=_pad_last2(codebooks.astype(cb_dtype), pn, C),
-        syms=pad_rows(syms),
-        offs=pad_rows(offs),
-        dbase=pad_rows(dbase),
+        codes=_planes(dense_codes, n_bits, pn, pk),
+        bitmap=None if fmt == "v2" else _planes(
+            packing.unpack_codes(bitmap, 1, d_in), 1, pn, pk),
+        codebooks=lanes(codebooks.astype(cb_dtype)),
+        syms=lanes(syms),
+        offs=lanes(offs),
+        dbase=lanes(dbase),
         n_bits=n_bits,
         d_out=d_out,
         d_in=d_in,
@@ -536,8 +554,8 @@ def prepare(
         # outside its layer scan, but building the memo for that case
         # would charge ~1 b/w of real TPU HBM to speed up a path the
         # scan-sliced serving hot loop never takes.
-        sel = _xla_selector(prep).astype(jnp.uint32)
-        prep = dataclasses.replace(prep, sel_memo=packing.pack_codes(sel, 1))
+        sel = _xla_selector(prep)
+        prep = dataclasses.replace(prep, sel_memo=_planes(sel, 1, pn, pk))
     return prep
 
 
@@ -617,13 +635,11 @@ def arm_blocks(M: int, prep: ICQPrepared) -> Tuple[int, int, int]:
     (``autotune.PREFILL_MS`` entries for the fused arm, the M-free
     ``dequant_key`` winner for the dequant arm) can re-block each arm
     independently. A winner is only adopted when it tiles the prepared
-    padding exactly (pn % bn == pk % bk == 0; v2 additionally pins
-    block_k to the prepared checkpoint tile — re-tiling K would need a
-    re-prepare); otherwise the prepare-time blocks stand.
+    layout legally (``blocks_fit``; v2 additionally pins block_k to the
+    prepared checkpoint tile — re-tiling K would need a re-prepare);
+    otherwise the prepare-time blocks stand.
     """
     base = (prep.block_m, prep.block_n, prep.block_k)
-    pn = prep.codes.shape[-2]
-    pk = prep.codes.shape[-1] * (32 // prep.n_bits)
     path = choose_path(M, prep)
     if path == "fused":
         hit = autotune.lookup(autotune.matmul_key(
@@ -632,24 +648,20 @@ def arm_blocks(M: int, prep: ICQPrepared) -> Tuple[int, int, int]:
         if hit is None:
             return base
         bm, bn, bk = hit
-        if prep.fmt == "v2":
-            bk = prep.block_k
-        if bm < 1 or bn < 1 or bk < 1 or pn % bn or pk % bk:
-            return base
-        return bm, bn, bk
-    if path == "dequant":
+    elif path == "dequant":
         hit = autotune.lookup(autotune.dequant_key(
             prep.d_out, prep.d_in, prep.n_bits, "pallas", prep.interpret,
             fmt=prep.fmt))
         if hit is None:
             return base
-        br, bc = hit
-        if prep.fmt == "v2":
-            bc = prep.block_k
-        if br < 1 or bc < 1 or pn % br or pk % bc:
-            return base
-        return prep.block_m, br, bc
-    return base
+        bm, (bn, bk) = prep.block_m, hit
+    else:
+        return base
+    if prep.fmt == "v2":
+        bk = prep.block_k
+    if bm < 8 or bm % 8 or not blocks_fit(bn, bk, prep.pn, prep.pk):
+        return base
+    return bm, bn, bk
 
 
 # ---------------------------------------------------------------------------
@@ -658,23 +670,21 @@ def arm_blocks(M: int, prep: ICQPrepared) -> Tuple[int, int, int]:
 
 def _xla_selector(prep: ICQPrepared) -> jnp.ndarray:
     """(*lead, d_out, d_in) int32 selector via the prepared tensors."""
-    if prep.sel_memo is not None:
-        # prepare-time memo of the v2 stream decode below (bit-identical
-        # by construction): per-call cost drops to one shift/mask unpack.
-        return packing.unpack_codes(
-            prep.sel_memo, 1, prep.d_in).astype(jnp.int32)
-    if prep.fmt == "v1":
-        return packing.unpack_codes(
-            prep.bitmap[..., : prep.d_out, :], 1, prep.d_in
-        ).astype(jnp.int32)
-    S = packing.symbol_cols(prep.syms.shape[-1], prep.b)
-    sym = packing.unpack_codes(prep.syms[..., : prep.d_out, :], prep.b, S)
+    plane = prep.sel_memo if prep.sel_memo is not None else prep.bitmap
+    if plane is not None:
+        # v1 bitmap, or the prepare-time memo of the v2 stream decode
+        # below (bit-identical by construction): one shift/mask unpack.
+        sel = packing.unpack_planes(plane, 1)
+        return sel[..., : prep.d_out, : prep.d_in]
+    syms = jnp.swapaxes(prep.syms[..., : prep.d_out], -1, -2)
+    S = packing.symbol_cols(syms.shape[-1], prep.b)
+    sym = packing.unpack_codes(syms, prep.b, S)
     lead = sym.shape[:-2]
     rows = int(math.prod(lead)) * prep.d_out
-    # counts live in the checkpoint sentinel column; the global-cumsum
+    # counts live in the checkpoint sentinel row; the global-cumsum
     # decode is bit-identical to the kernels' per-tile checkpoint decode
     # (same positions) at a fraction of the work.
-    counts = prep.offs[..., : prep.d_out, -1].reshape(rows)
+    counts = prep.offs[..., -1, : prep.d_out].reshape(rows)
     sel = selector_from_stream_cols(
         sym.reshape(rows, S).astype(jnp.int32), counts,
         b=prep.b, out_len=prep.d_in,
@@ -685,56 +695,66 @@ def _xla_selector(prep: ICQPrepared) -> jnp.ndarray:
 def _xla_weight(prep: ICQPrepared) -> jnp.ndarray:
     """Prepared tensors -> (*lead, d_out, d_in) weights, pure XLA.
 
-    v1 unpacks the bitmap; v2 decodes the gap stream in-graph with the
-    same exact integer math as the kernels' checkpoint decode, so the
-    selector — and therefore the gathered weight — is bit-identical
+    v1 unpacks the bitmap plane; v2 decodes the gap stream in-graph with
+    the same exact integer math as the kernels' checkpoint decode, so
+    the selector — and therefore the gathered weight — is bit-identical
     across formats and to the reference ``dequantize`` path. Output
     dtype follows the stored codebooks (f32, or bf16 codebook cache).
     """
-    codes = packing.unpack_codes(
-        prep.codes[..., : prep.d_out, :], prep.n_bits, prep.d_in
-    ).astype(jnp.int32)
+    codes = packing.unpack_planes(prep.codes, prep.n_bits)
+    codes = codes[..., : prep.d_out, : prep.d_in]
     idx = _xla_selector(prep) * (1 << prep.n_bits) + codes
-    return jnp.take_along_axis(
-        prep.codebooks[..., : prep.d_out, :], idx, axis=-1)
+    cb = jnp.swapaxes(prep.codebooks[..., : prep.d_out], -1, -2)
+    return jnp.take_along_axis(cb, idx, axis=-1)
 
 
-def _rows2(x: jnp.ndarray) -> jnp.ndarray:
-    """Fold leading stack axes of a prepared child into rows."""
-    return x.reshape(-1, x.shape[-1])
+def _dequant_t(prep: ICQPrepared, block_n: int, block_k: int) -> jnp.ndarray:
+    """Dequant kernel over a prepared weight -> (*lead, pk, pn) f32 W^T
+    (still padded). Leading stack axes become the kernel's L grid axis."""
+    lead = prep.codes.shape[:-2]
+
+    def stack(x):
+        return x.reshape((-1,) + x.shape[-2:])
+
+    if prep.fmt == "v2":
+        out = dequant_padded_v2(
+            stack(prep.codes), stack(prep.syms), stack(prep.offs),
+            stack(prep.dbase), stack(prep.codebooks),
+            n_bits=prep.n_bits, b=prep.b, block_n=block_n,
+            interpret=prep.interpret,
+        )
+    else:
+        out = dequant_padded(
+            stack(prep.codes), stack(prep.bitmap), stack(prep.codebooks),
+            n_bits=prep.n_bits, block_n=block_n, block_k=block_k,
+            interpret=prep.interpret,
+        )
+    return out.reshape(*lead, prep.pk, prep.pn)
+
+
+def _fused(x_p: jnp.ndarray, prep: ICQPrepared, block_m: int, block_n: int,
+           block_k: int, accum: Optional[str] = None) -> jnp.ndarray:
+    """Fused kernel: (pm, pk) f32 activations -> (pm, pn) f32 (padded)."""
+    accum = default_accum_dtype() if accum is None else accum
+    if prep.fmt == "v2":
+        return matmul_padded_v2(
+            x_p, prep.codes, prep.syms, prep.offs, prep.dbase,
+            prep.codebooks, n_bits=prep.n_bits, b=prep.b, block_m=block_m,
+            block_n=block_n, interpret=prep.interpret, accum=accum)
+    return matmul_padded(
+        x_p, prep.codes, prep.bitmap, prep.codebooks, n_bits=prep.n_bits,
+        block_m=block_m, block_n=block_n, block_k=block_k,
+        interpret=prep.interpret, accum=accum)
 
 
 def dequantize_prepared(prep: ICQPrepared) -> jnp.ndarray:
     """Materialize (*lead, d_out, d_in) weights. Pallas backend runs the
-    dequant kernel (leading axes fold into grid rows — dequantization is
-    row-independent, so stacks need one kernel call, not a vmap)."""
+    dequant kernel (one call for a whole stack — dequantization is
+    row-independent, so stacks need no vmap)."""
     if prep.backend != "pallas":
         return _xla_weight(prep)
-    k = 32 // prep.n_bits
-    lead = prep.codes.shape[:-2]
-    pn = prep.codes.shape[-2]
-    pk = prep.codes.shape[-1] * k
-    onehot = default_onehot_dtype()
-    if prep.fmt == "v2":
-        out = dequant_padded_v2(
-            _rows2(prep.codes),
-            _rows2(prep.syms),
-            _rows2(prep.offs),
-            _rows2(prep.dbase),
-            _rows2(prep.codebooks),
-            n_bits=prep.n_bits, b=prep.b, block_r=prep.block_n,
-            interpret=prep.interpret, onehot=onehot,
-        )
-    else:
-        out = dequant_padded(
-            _rows2(prep.codes),
-            _rows2(prep.bitmap),
-            _rows2(prep.codebooks),
-            n_bits=prep.n_bits, block_r=prep.block_n, block_c=prep.block_k,
-            interpret=prep.interpret, onehot=onehot,
-        )
-    out = out.reshape(*lead, pn, pk)
-    return out[..., : prep.d_out, : prep.d_in]
+    wt = _dequant_t(prep, prep.block_n, prep.block_k)
+    return jnp.swapaxes(wt[..., : prep.d_in, : prep.d_out], -1, -2)
 
 
 def linear_apply(x: jnp.ndarray, prep: ICQPrepared) -> jnp.ndarray:
@@ -753,51 +773,18 @@ def linear_apply(x: jnp.ndarray, prep: ICQPrepared) -> jnp.ndarray:
         w = _xla_weight(prep)
         return x @ jnp.swapaxes(w, -1, -2).astype(x.dtype)
 
-    pk = prep.codes.shape[-1] * (32 // prep.n_bits)
     x2 = x.reshape(M, prep.d_in).astype(jnp.float32)
-    abm, abn, abk = arm_blocks(M, prep)   # per-arm autotuned block table
-    onehot = default_onehot_dtype()
-
+    bm, bn, bk = arm_blocks(M, prep)   # per-arm autotuned block table
     if path == "fused":
-        accum = default_accum_dtype()
-        bm = min(abm, _round_up(M, 8))
+        bm = min(bm, _round_up(M, 8))
         pm = _round_up(M, bm)
-        x_p = jnp.pad(x2, ((0, pm - M), (0, pk - prep.d_in)))
-        if prep.fmt == "v2":
-            y = matmul_padded_v2(
-                x_p, prep.codes, prep.syms, prep.offs, prep.dbase,
-                prep.codebooks,
-                n_bits=prep.n_bits, b=prep.b, block_m=bm,
-                block_n=abn, interpret=prep.interpret, onehot=onehot,
-                accum=accum,
-            )[:M, : prep.d_out]
-        else:
-            y = matmul_padded(
-                x_p, prep.codes, prep.bitmap, prep.codebooks,
-                n_bits=prep.n_bits, block_m=bm, block_n=abn,
-                block_k=abk, interpret=prep.interpret, onehot=onehot,
-                accum=accum,
-            )[:M, : prep.d_out]
-    else:  # 'dequant': reconstruct once, ride the dense MXU matmul
-        if prep.fmt == "v2":
-            w = dequant_padded_v2(
-                prep.codes, prep.syms, prep.offs, prep.dbase,
-                prep.codebooks,
-                n_bits=prep.n_bits, b=prep.b, block_r=abn,
-                interpret=prep.interpret, onehot=onehot,
-            )                                        # (pn, pk)
-        else:
-            w = dequant_padded(
-                prep.codes, prep.bitmap, prep.codebooks,
-                n_bits=prep.n_bits, block_r=abn,
-                block_c=abk, interpret=prep.interpret, onehot=onehot,
-            )                                        # (pn, pk)
-        x_p = jnp.pad(x2, ((0, 0), (0, pk - prep.d_in)))
-        y = jax.lax.dot_general(
-            x_p, w, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )[:, : prep.d_out]
-
+        x_p = jnp.pad(x2, ((0, pm - M), (0, prep.pk - prep.d_in)))
+        y = _fused(x_p, prep, bm, bn, bk)[:M, : prep.d_out]
+    else:  # 'dequant': reconstruct W^T once, ride the dense MXU matmul
+        wt = _dequant_t(prep, bn, bk)                 # (pk, pn)
+        x_p = jnp.pad(x2, ((0, 0), (0, prep.pk - prep.d_in)))
+        y = jnp.dot(x_p, wt, preferred_element_type=jnp.float32
+                    )[:, : prep.d_out]
     return y.reshape(*x.shape[:-1], prep.d_out).astype(x.dtype)
 
 
@@ -807,11 +794,14 @@ __all__ = [
     "prepare",
     "prepare_tree",
     "arm_blocks",
+    "blocks_fit",
     "bucket_m",
     "choose_path",
     "dequantize_prepared",
     "forced_backend",
     "linear_apply",
+    "snap_block_k",
+    "snap_block_n",
     "verify_runtime_integrity",
     "vmem_bytes_estimate",
     "vmem_budget_bytes",

@@ -1,361 +1,250 @@
-"""Pallas TPU kernel: ICQuant tile dequantization.
+"""Pallas TPU kernel: ICQuant tile dequantization (+ the shared tile math).
 
-HBM->VMEM traffic per output tile is n/16 + 1/16th of the bf16 baseline
-(packed codes + 1-bit selector bitmap + one codebook row pair); the
-unpack is shift/mask on the VPU and the codebook lookup is a one-hot
-``dot_general`` over the C <= 2^(n+1) levels — a (BR, BC, C) x (BR, C)
-batched contraction that rides the MXU instead of C serial VPU selects.
+Every prepared weight tensor is stored transposed (``core/packing.py``
+bit planes): the lane axis is the weight row (d_out), the sublane axis
+runs along d_in. A kernel therefore rebuilds a transposed weight tile
+``W^T[k, r]`` of shape (BK, BN) in VMEM:
 
-Block layout: grid (d_out/BR, d_in/BC); code words and bitmap words are
-blocked along the same column tiles (BC is a multiple of lcm(k, 32) for
-the v1 bitmap format, of k alone for v2 — there is no bitmap to align).
+  * codes: n bit planes, unpacked with shifts and masks, 8 sublanes at a
+    time (no minor-dim reshape);
+  * selector: v1 reads a 1-bit plane in the same layout; v2 decodes its
+    tile of the checkpointed gap stream (``_tile_selector``);
+  * codebook: a binary tree of selects over the 2^(n+1) per-row levels
+    on the VPU — each weight is one of its row's stored levels, exactly.
 
-Two runtime formats share the kernels:
-  * v1 — dense 1-bit selector bitmap (``dequant_padded``): selector
-    unpack is shift/mask, HBM overhead ~1 bit/weight.
-  * v2 — checkpointed gap stream (``dequant_padded_v2``): the block
-    reconstructs its selector locally from b-bit gap symbols + per-tile
-    checkpoints via ``_decode_block_selector`` (a short masked cumsum),
-    HBM overhead ~0.35-0.45 bit/weight. ``block_c`` must equal the
-    checkpoint tile the sidecar was built for.
-
-Two entry points per format:
-  * ``dequant_padded[_v2]`` — the hot-path cores. Inputs must already be
-    padded/blocked (see kernels/backend.py ``prepare``); no per-call
-    reshape or ``jnp.pad`` happens here.
-  * ``icq_dequant[_v2]``   — convenience wrappers that pad on the fly
-    (benchmarks, tests, one-off calls).
-
-``interpret=None`` resolves via kernels.platform: compiled on TPU,
-interpreter everywhere else.
+Grid of ``dequant_padded[_v2]``: (L, pn / BN, pk / BK) over a stack of L
+matrices; the output is the transposed reconstruction (L, pk, pn) f32,
+still padded. ``backend.prepare`` builds the inputs; ``interpret`` comes
+from kernels.platform (compiled on TPU, the interpreter elsewhere).
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.platform import default_interpret, default_onehot_dtype
-
-# v2 selector decode: symbols compared against the column iota in chunks
-# of this many symbols, bounding the (BR, chunk, BC) one-hot temporary.
-SEL_CHUNK = 16
-
-# dtype of the (BR, BC, C) codebook-select one-hot temporary (the
-# dominant VMEM term): 'f32' is exact, 'bf16' halves it — see
-# platform.default_onehot_dtype / ICQ_ONEHOT_DTYPE.
-ONEHOT_DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16}
-
-
-def check_onehot(onehot: str) -> str:
-    """Validate an explicit ``onehot`` kwarg (the env route validates in
-    platform.default_onehot_dtype; this keeps kwarg misuse a ValueError
-    instead of a KeyError mid-trace)."""
-    if onehot not in ONEHOT_DTYPES:
-        raise ValueError(
-            f"onehot must be one of {sorted(ONEHOT_DTYPES)}, got {onehot!r}")
-    return onehot
-
-
-def onehot_itemsize(onehot: Optional[str] = None) -> int:
-    """Bytes per element of the one-hot select temporary (VMEM budgeting)."""
-    if onehot is None:
-        onehot = default_onehot_dtype()
-    return jnp.dtype(ONEHOT_DTYPES[check_onehot(onehot)]).itemsize
-
-
-def _unpack_block(words: jnp.ndarray, n_bits: int, out_cols: int) -> jnp.ndarray:
-    """(BR, W) uint32 -> (BR, out_cols) int32 of n-bit fields."""
-    k = 32 // n_bits
-    mask = jnp.uint32((1 << n_bits) - 1)
-    shifts = (jnp.arange(k, dtype=jnp.uint32) * n_bits)[None, None, :]
-    fields = (words[:, :, None] >> shifts) & mask
-    return fields.reshape(words.shape[0], -1)[:, :out_cols].astype(jnp.int32)
-
-
-def _codebook_select(idx: jnp.ndarray, codebooks: jnp.ndarray,
-                     onehot: str = "f32") -> jnp.ndarray:
-    """idx: (BR, BC) int32 in [0, C); codebooks: (BR, C) -> (BR, BC) f32.
-
-    One-hot gather as a single batched dot_general (batch dim = row):
-    the (BR, BC, C) one-hot contracts against the row codebook on the
-    MXU in one shot, instead of the C-step unrolled where-select chain
-    the VPU had to chew through serially.
-
-    ``onehot='bf16'`` halves the (BR, BC, C) temporary (one-hot entries
-    are exact 0/1 in bf16; the f32-accumulated dot then returns each
-    codebook level rounded to bf16 — ~3 decimal digits of level
-    precision, the same loss as a bf16 codebook cache).
-    """
-    C = codebooks.shape[-1]
-    dt = ONEHOT_DTYPES[onehot]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, C), 2)
-    oh = (idx[:, :, None] == iota).astype(dt)
-    return jax.lax.dot_general(
-        oh, codebooks.astype(dt),
-        (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )
-
-
-def _decode_block_selector(syms: jnp.ndarray, offs: jnp.ndarray,
-                           dbase: jnp.ndarray, kk, *,
-                           b: int, block_k: int) -> jnp.ndarray:
-    """Checkpointed gap stream -> (BR, block_k) 0/1 selector for tile kk.
-
-    syms:  (BR, SW) uint32 — the row's full packed b-bit symbol stream
-           (value-1 encoding, all-ones = escape flag).
-    offs:  (BR, T+1) uint16 — symbol offset at every tile boundary
-           (sentinel column = per-row symbol count).
-    dbase: (BR, T) uint8/uint16 — kk*block_k - dbase[kk] is the absolute
-           position consumed before the tile's first symbol.
-    kk:    column-tile index (pl.program_id of the K grid axis).
-
-    Decode is block-local: mask the stream to [offs[kk], offs[kk+1]),
-    cumsum the masked gap increments (escape = 2^b - 1 positions, no
-    emission) on top of the checkpoint base, then scatter-by-compare the
-    emitted positions against the tile's column iota. No row prefix is
-    scanned and no dense bitmap ever exists.
-    """
-    k_b = 32 // b
-    S = syms.shape[-1] * k_b
-    sym = _unpack_block(syms, b, S)                            # (BR, S)
-    off = offs.astype(jnp.int32)
-    pair = jax.lax.dynamic_slice_in_dim(off, kk, 2, axis=1)    # (BR, 2)
-    o0, o1 = pair[:, :1], pair[:, 1:]
-    d0 = jax.lax.dynamic_slice_in_dim(
-        dbase.astype(jnp.int32), kk, 1, axis=1)                # (BR, 1)
-    j = jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
-    in_tile = (j >= o0) & (j < o1)
-    m = (1 << b) - 1
-    inc = jnp.where(sym == m, m, sym + 1) * in_tile.astype(jnp.int32)
-    rel = jnp.cumsum(inc, axis=-1) - d0 - 1          # position - kk*block_k
-    emit = in_tile & (sym != m)
-    sel = jnp.zeros((syms.shape[0], block_k), jnp.int32)
-    iota_c = jax.lax.broadcasted_iota(jnp.int32, (1, 1, block_k), 2)
-    for s0 in range(0, S, SEL_CHUNK):
-        r = rel[:, s0:s0 + SEL_CHUNK]
-        e = emit[:, s0:s0 + SEL_CHUNK]
-        hit = (r[:, :, None] == iota_c) & e[:, :, None]
-        sel = sel + hit.astype(jnp.int32).sum(axis=1)
-    return sel
-
-
-def _dequant_kernel(codes_ref, bitmap_ref, cb_ref, out_ref, *, n_bits: int,
-                    onehot: str):
-    BC = out_ref.shape[-1]
-    codes = _unpack_block(codes_ref[...], n_bits, BC)
-    sel = _unpack_block(bitmap_ref[...], 1, BC)
-    idx = sel * (1 << n_bits) + codes
-    out_ref[...] = _codebook_select(idx, cb_ref[...], onehot)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_bits", "block_r", "block_c", "interpret", "onehot"),
-)
-def dequant_padded(
-    codes: jnp.ndarray,      # (pr, pc // k) uint32, pr % block_r == 0
-    bitmap: jnp.ndarray,     # (pr, pc // 32) uint32
-    codebooks: jnp.ndarray,  # (pr, C) f32
-    *,
-    n_bits: int,
-    block_r: int,
-    block_c: int,
-    interpret: bool,
-    onehot: str = "f32",
-) -> jnp.ndarray:
-    """Core kernel over pre-blocked inputs -> (pr, pc) f32 (still padded)."""
-    check_onehot(onehot)
-    k = 32 // n_bits
-    pr, pc = codes.shape[0], codes.shape[1] * k
-    grid = (pr // block_r, pc // block_c)
-    C = codebooks.shape[1]
-    return pl.pallas_call(
-        functools.partial(_dequant_kernel, n_bits=n_bits, onehot=onehot),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_r, block_c // k), lambda i, j: (i, j)),
-            pl.BlockSpec((block_r, block_c // 32), lambda i, j: (i, j)),
-            pl.BlockSpec((block_r, C), lambda i, j: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_r, block_c), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((pr, pc), jnp.float32),
-        interpret=interpret,
-    )(codes, bitmap, codebooks)
-
-
-def _dequant_kernel_v2(codes_ref, syms_ref, offs_ref, dbase_ref, cb_ref,
-                       out_ref, *, n_bits: int, b: int, onehot: str):
-    BC = out_ref.shape[-1]
-    codes = _unpack_block(codes_ref[...], n_bits, BC)
-    sel = _decode_block_selector(
-        syms_ref[...], offs_ref[...], dbase_ref[...], pl.program_id(1),
-        b=b, block_k=BC,
-    )
-    idx = sel * (1 << n_bits) + codes
-    out_ref[...] = _codebook_select(idx, cb_ref[...], onehot)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_bits", "b", "block_r", "interpret", "onehot"),
-)
-def dequant_padded_v2(
-    codes: jnp.ndarray,      # (pr, pc // k) uint32, pr % block_r == 0
-    syms: jnp.ndarray,       # (pr, SW) uint32 packed b-bit gap symbols
-    offs: jnp.ndarray,       # (pr, T+1) uint16 tile symbol offsets
-    dbase: jnp.ndarray,      # (pr, T) uint8/uint16 tile base deltas
-    codebooks: jnp.ndarray,  # (pr, C)
-    *,
-    n_bits: int,
-    b: int,
-    block_r: int,
-    interpret: bool,
-    onehot: str = "f32",
-) -> jnp.ndarray:
-    """v2 core over pre-blocked inputs -> (pr, pc) f32 (still padded).
-
-    The column block is the checkpoint tile: block_c = pc / T, where T
-    comes from the sidecar shape (``prepare`` guarantees pc == T * tile).
-    """
-    check_onehot(onehot)
-    k = 32 // n_bits
-    pr, pc = codes.shape[0], codes.shape[1] * k
-    T = offs.shape[1] - 1
-    block_c = pc // T
-    grid = (pr // block_r, T)
-    C = codebooks.shape[1]
-    SW = syms.shape[1]
-    return pl.pallas_call(
-        functools.partial(_dequant_kernel_v2, n_bits=n_bits, b=b,
-                          onehot=onehot),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_r, block_c // k), lambda i, j: (i, j)),
-            pl.BlockSpec((block_r, SW), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_r, T + 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_r, T), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_r, C), lambda i, j: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_r, block_c), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((pr, pc), jnp.float32),
-        interpret=interpret,
-    )(codes, syms, offs, dbase, codebooks)
-
-
-def icq_dequant_v2(
-    codes: jnp.ndarray,      # (d_out, Wc) uint32
-    syms: jnp.ndarray,       # (d_out, SW) uint32
-    offs: jnp.ndarray,       # (d_out, T+1) uint16
-    dbase: jnp.ndarray,      # (d_out, T) uint8/uint16
-    codebooks: jnp.ndarray,  # (d_out, 2^(n+1))
-    *,
-    n_bits: int,
-    b: int,
-    d_in: int,
-    tile: int,
-    block_r: int = 256,
-    interpret: Optional[bool] = None,
-    onehot: Optional[str] = None,
-) -> jnp.ndarray:
-    """Pad-on-the-fly v2 wrapper -> (d_out, d_in) f32 reconstruction."""
-    if interpret is None:
-        interpret = default_interpret()
-    if onehot is None:
-        onehot = default_onehot_dtype()
-    d_out = codes.shape[0]
-    k = 32 // n_bits
-    T = offs.shape[1] - 1
-    pc = T * tile
-    br = min(block_r, _round_up(d_out, 8))
-    pr = _round_up(d_out, br)
-    out = dequant_padded_v2(
-        _pad2(codes, pr, pc // k),
-        _pad2(syms, pr, syms.shape[1]),
-        _pad2(offs, pr, offs.shape[1]),
-        _pad2(dbase, pr, dbase.shape[1]),
-        _pad2(codebooks, pr, codebooks.shape[1]),
-        n_bits=n_bits, b=b, block_r=br, interpret=interpret, onehot=onehot,
-    )
-    return out[:d_out, :d_in]
-
-
-def snap_block_k(d_in: int, lcm: int, block_k: int) -> int:
-    """Largest lcm-multiple <= block_k that divides round_up(d_in, lcm).
-
-    Dividing the minimal padded width (instead of rounding the padded
-    width up to the block) keeps K padding at < lcm columns — naive
-    snapping cost ~17% extra HBM traffic for n_bits=3 geometries."""
-    q = _round_up(d_in, lcm) // lcm
-    t_req = min(max(1, block_k // lcm), q)
-    t = max(d for d in range(1, t_req + 1) if q % d == 0)
-    return lcm * t
-
-
-def column_granularity(n_bits: int, fmt: str = "v1") -> int:
-    """Smallest legal column-block unit: code words and (v1 only) bitmap
-    words must block on the same column tiles. v2 has no bitmap, so only
-    the k = 32//n code-packing granularity binds — for n=3 (k=10) that
-    drops the unit from lcm(10, 32)=160 to 10 and lets the checkpoint
-    tile stay large (checkpoint cost scales as 1/tile)."""
-    k = 32 // n_bits
-    return k if fmt == "v2" else (k * 32) // _gcd(k, 32)
-
-
-def dequant_blocks(d_out: int, d_in: int, n_bits: int,
-                   block_r: int, block_c: int, fmt: str = "v1"):
-    """Snap requested blocks to the packing granularities -> (br, bc)."""
-    lcm = column_granularity(n_bits, fmt)
-    br = min(block_r, _round_up(d_out, 8))
-    return br, snap_block_k(d_in, lcm, block_c)
-
-
-def icq_dequant(
-    codes: jnp.ndarray,      # (d_out, Wc) uint32
-    bitmap: jnp.ndarray,     # (d_out, Wb) uint32
-    codebooks: jnp.ndarray,  # (d_out, 2^(n+1)) f32
-    *,
-    n_bits: int,
-    d_in: int,
-    block_r: int = 256,
-    block_c: int = 512,
-    interpret: Optional[bool] = None,
-    onehot: Optional[str] = None,
-) -> jnp.ndarray:
-    """Pad-on-the-fly wrapper -> (d_out, d_in) f32 reconstruction."""
-    if interpret is None:
-        interpret = default_interpret()
-    if onehot is None:
-        onehot = default_onehot_dtype()
-    d_out = codes.shape[0]
-    k = 32 // n_bits
-    br, bc = dequant_blocks(d_out, d_in, n_bits, block_r, block_c)
-    pc = _round_up(d_in, bc)
-    pr = _round_up(d_out, br)
-    codes_p = _pad2(codes, pr, pc // k)
-    bitmap_p = _pad2(bitmap, pr, pc // 32)
-    cb_p = _pad2(codebooks, pr, codebooks.shape[1])
-    out = dequant_padded(
-        codes_p, bitmap_p, cb_p,
-        n_bits=n_bits, block_r=br, block_c=bc, interpret=interpret,
-        onehot=onehot,
-    )
-    return out[:d_out, :d_in]
-
-
-def _gcd(a: int, b: int) -> int:
-    while b:
-        a, b = b, a % b
-    return a
+from repro.core.packing import PLANE_ROWS
 
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def _pad2(x, r, c):
-    return jnp.pad(x, ((0, r - x.shape[0]), (0, c - x.shape[1])))
+def _as_i32(words: jnp.ndarray) -> jnp.ndarray:
+    return jax.lax.bitcast_convert_type(words, jnp.int32)
+
+
+def _srl(x: jnp.ndarray, s) -> jnp.ndarray:
+    return jax.lax.shift_right_logical(x, jnp.asarray(s, x.dtype))
+
+
+def _unpack_planes(words: jnp.ndarray, n_bits: int) -> jnp.ndarray:
+    """(n*BK/32, BN) uint32 bit-plane words -> (BK, BN) int32 codes."""
+    G = words.shape[0] // (8 * n_bits)
+    BN = words.shape[1]
+    w = _as_i32(words).reshape(G, n_bits, 8, BN)
+    j = jax.lax.broadcasted_iota(jnp.int32, (1, 32, 8, BN), 1)
+    code = None
+    for p in range(n_bits):
+        bit = _srl(w[:, p][:, None], j) & 1                  # (G, 32, 8, BN)
+        code = bit if code is None else code | (bit << p)
+    return code.reshape(G * PLANE_ROWS, BN)
+
+
+def _codebook_select(idx: jnp.ndarray, cb: jnp.ndarray) -> jnp.ndarray:
+    """idx (BK, BN) int32 in [0, C); cb (C, BN) per-row levels ->
+    (BK, BN) f32. A binary tree of selects on the bits of idx: the
+    result is a stored level bit for bit (no MXU rounding)."""
+    cb = cb.astype(jnp.float32)
+    levels = [cb[c:c + 1, :] for c in range(cb.shape[0])]
+    bit = 0
+    while len(levels) > 1:
+        hi = (_srl(idx, bit) & 1) == 1
+        levels = [jnp.where(hi, levels[2 * i + 1], levels[2 * i])
+                  for i in range(len(levels) // 2)]
+        bit += 1
+    return jnp.broadcast_to(levels[0], idx.shape)
+
+
+def _row_at(x: jnp.ndarray, t) -> jnp.ndarray:
+    """Row t (traced) of a small (R, BN) int table as (1, BN): an
+    iota-masked sum, since Mosaic has no dynamic minor-dim slice."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    return jnp.sum(jnp.where(rows == t, x.astype(jnp.int32), 0), axis=0,
+                   keepdims=True)
+
+
+def _exclusive_prefix_rows(x: jnp.ndarray) -> jnp.ndarray:
+    """Exclusive prefix sum down the rows of a non-negative (W, BN) int32
+    array, exact: a strictly-lower-triangular 0/1 matmul over 8-bit
+    chunks of x, each exact in bf16 with f32 accumulation (sums < 2^24)."""
+    W = x.shape[0]
+    lower = (jax.lax.broadcasted_iota(jnp.int32, (W, W), 0)
+             > jax.lax.broadcasted_iota(jnp.int32, (W, W), 1)
+             ).astype(jnp.bfloat16)
+    out = jnp.zeros(x.shape, jnp.int32)
+    for shift in range(0, 24, 8):
+        chunk = (_srl(x, shift) & 255).astype(jnp.bfloat16)
+        part = jax.lax.dot_general(
+            lower, chunk, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        out = out + (part.astype(jnp.int32) << shift)
+    return out
+
+
+def _tile_selector(syms: jnp.ndarray, offs: jnp.ndarray, dbase: jnp.ndarray,
+                   kk, rel_ref, *, b: int, block_k: int) -> jnp.ndarray:
+    """Checkpointed gap stream -> (BK, BN) 0/1 selector of K tile kk.
+
+    syms:  (SW, BN) uint32 — each row's packed b-bit gap symbols down
+           the sublanes (storage packing: symbol w*k_b + j in field j of
+           word w; value-1 encoding, all-ones = escape flag).
+    offs:  (T+1, BN) — symbol offset at every tile boundary (last row =
+           the row's symbol count).
+    dbase: (T, BN) — kk*block_k - dbase[kk] is the position consumed
+           before the tile's first symbol.
+    rel_ref: (k_b * round_up(SW, 8), BN) int32 VMEM scratch.
+
+    Only the tile's symbols [offs[kk], offs[kk+1]) contribute: their
+    increments (escape = 2^b - 1 positions, no emission) are prefix
+    summed exactly on top of the checkpoint base, and each emitted
+    position sets its selector row. No dense bitmap ever exists.
+    """
+    k_b = 32 // b
+    m = (1 << b) - 1
+    SW, BN = syms.shape
+    SWp = rel_ref.shape[0] // k_b
+    o0, o1 = _row_at(offs, kk), _row_at(offs, kk + 1)         # (1, BN)
+    d0 = _row_at(dbase, kk)
+    w = _as_i32(syms)
+    s0 = jax.lax.broadcasted_iota(jnp.int32, (SW, BN), 0) * k_b
+    incs, emits = [], []
+    for j in range(k_b):
+        sym = _srl(w, j * b) & m
+        inside = (s0 + j >= o0) & (s0 + j < o1)
+        incs.append(jnp.where(inside, jnp.where(sym == m, m, sym + 1), 0))
+        emits.append(inside & (sym != m))
+    pre = []                                   # inclusive, within a word
+    for inc in incs:
+        pre.append(inc if not pre else pre[-1] + inc)
+    base = _exclusive_prefix_rows(pre[-1]) - d0 - 1
+    for j in range(k_b):
+        rel_ref[j * SWp:j * SWp + SW, :] = jnp.where(
+            emits[j], base + pre[j], -1)                      # -1: no hit
+
+    rows = jax.lax.broadcasted_iota(jnp.int32, (block_k, BN), 0)
+
+    def body(i, sel):
+        for j in range(k_b):
+            rel = rel_ref[pl.ds(j * SWp + i, 1), :]           # (1, BN)
+            sel = jnp.where(rel == rows, 1, sel)
+        return sel
+
+    return jax.lax.fori_loop(0, SW, body,
+                             jnp.zeros((block_k, BN), jnp.int32))
+
+
+def rel_scratch(SW: int, b: int, block_n: int):
+    """VMEM scratch of ``_tile_selector``."""
+    return pltpu.VMEM(((32 // b) * _round_up(SW, 8), block_n), jnp.int32)
+
+
+def _dequant_kernel(codes_ref, bitmap_ref, cb_ref, out_ref, *, n_bits: int):
+    sel = _unpack_planes(bitmap_ref[...], 1)
+    idx = (sel << n_bits) + _unpack_planes(codes_ref[...], n_bits)
+    out_ref[...] = _codebook_select(idx, cb_ref[...])
+
+
+def _dequant_kernel_v2(codes_ref, syms_ref, offs_ref, dbase_ref, cb_ref,
+                       out_ref, rel_ref, *, n_bits: int, b: int):
+    BK = out_ref.shape[0]
+    sel = _tile_selector(syms_ref[...], offs_ref[...], dbase_ref[...],
+                         pl.program_id(2), rel_ref, b=b, block_k=BK)
+    idx = (sel << n_bits) + _unpack_planes(codes_ref[...], n_bits)
+    out_ref[...] = _codebook_select(idx, cb_ref[...])
+
+
+def _stack_spec(rows: int, block_n: int, k_blocked: bool):
+    """(L, rows_total, pn) operand blocked as (rows, block_n); its row
+    block follows the K grid axis when ``k_blocked``."""
+    if k_blocked:
+        return pl.BlockSpec((None, rows, block_n), lambda l, j, kk: (l, kk, j))
+    return pl.BlockSpec((None, rows, block_n), lambda l, j, kk: (l, 0, j))
+
+
+@functools.partial(
+    jax.jit, static_argnames=("n_bits", "block_n", "block_k", "interpret"))
+def dequant_padded(
+    codes: jnp.ndarray,      # (L, n*pk/32, pn) uint32 bit planes
+    bitmap: jnp.ndarray,     # (L, pk/32, pn) uint32 selector plane
+    codebooks: jnp.ndarray,  # (L, C, pn)
+    *,
+    n_bits: int,
+    block_n: int,
+    block_k: int,
+    interpret: bool,
+) -> jnp.ndarray:
+    """v1 core over prepared inputs -> (L, pk, pn) f32 W^T (padded)."""
+    L, _, pn = codes.shape
+    pk = bitmap.shape[1] * 32
+    C = codebooks.shape[1]
+    return pl.pallas_call(
+        functools.partial(_dequant_kernel, n_bits=n_bits),
+        grid=(L, pn // block_n, pk // block_k),
+        in_specs=[
+            _stack_spec(n_bits * block_k // 32, block_n, True),
+            _stack_spec(block_k // 32, block_n, True),
+            _stack_spec(C, block_n, False),
+        ],
+        out_specs=pl.BlockSpec((None, block_k, block_n),
+                               lambda l, j, kk: (l, kk, j)),
+        out_shape=jax.ShapeDtypeStruct((L, pk, pn), jnp.float32),
+        interpret=interpret,
+        name="icq_dequant_v1",
+    )(codes, bitmap, codebooks)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("n_bits", "b", "block_n", "interpret"))
+def dequant_padded_v2(
+    codes: jnp.ndarray,      # (L, n*pk/32, pn) uint32 bit planes
+    syms: jnp.ndarray,       # (L, SW, pn) uint32 packed gap symbols
+    offs: jnp.ndarray,       # (L, T+1, pn) uint16 tile symbol offsets
+    dbase: jnp.ndarray,      # (L, T, pn) uint8/uint16 tile base deltas
+    codebooks: jnp.ndarray,  # (L, C, pn)
+    *,
+    n_bits: int,
+    b: int,
+    block_n: int,
+    interpret: bool,
+) -> jnp.ndarray:
+    """v2 core over prepared inputs -> (L, pk, pn) f32 W^T (padded).
+
+    The K block is the checkpoint tile: pk / T, with T from the sidecar
+    shape (``prepare`` guarantees pk == T * tile).
+    """
+    L, W, pn = codes.shape
+    pk = W * 32 // n_bits
+    T = offs.shape[1] - 1
+    block_k = pk // T
+    SW = syms.shape[1]
+    return pl.pallas_call(
+        functools.partial(_dequant_kernel_v2, n_bits=n_bits, b=b),
+        grid=(L, pn // block_n, T),
+        in_specs=[
+            _stack_spec(n_bits * block_k // 32, block_n, True),
+            _stack_spec(SW, block_n, False),
+            _stack_spec(T + 1, block_n, False),
+            _stack_spec(T, block_n, False),
+            _stack_spec(codebooks.shape[1], block_n, False),
+        ],
+        out_specs=pl.BlockSpec((None, block_k, block_n),
+                               lambda l, j, kk: (l, kk, j)),
+        out_shape=jax.ShapeDtypeStruct((L, pk, pn), jnp.float32),
+        scratch_shapes=[rel_scratch(SW, b, block_n)],
+        interpret=interpret,
+        name="icq_dequant_v2",
+    )(codes, syms, offs, dbase, codebooks)

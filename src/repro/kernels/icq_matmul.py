@@ -1,24 +1,18 @@
 """Pallas TPU kernel: fused ICQuant dequantize + matmul.
 
-y = x @ W_hat.T with W stored packed (n-bit codes + 1-bit selector +
-per-row dual codebooks). The weight tile is dequantized in VMEM and fed
-straight to the MXU — HBM never sees the dense bf16 weights, so the
-memory roofline term for decode-bound serving drops by ~16/(n+1)x.
+y = x @ W_hat.T with W stored as prepared bit planes (``core/packing.py``)
+plus a per-row selector and dual codebooks. Each grid step rebuilds one
+transposed weight tile W^T (BK, BN) in VMEM (icq_dequant's tile math) and
+feeds it straight to the MXU — HBM never sees the dense weights.
 
-Grid (M/BM, N/BN, K/BK), K innermost; f32 accumulator lives in a VMEM
-scratch buffer and is flushed to the output tile at the last K step
-(standard Pallas matmul schedule, MXU-aligned tiles).
-
-Two entry points, mirroring icq_dequant:
-  * ``matmul_padded`` — hot-path core over pre-blocked weights (see
-    kernels/backend.py ``prepare``); only the activation was padded by
-    the caller, the weight tensors carry no per-call reshape/pad work.
-  * ``icq_matmul``    — pad-on-the-fly wrapper (tests, benchmarks).
+Grid (M/BM, N/BN, K/BK), K innermost; an f32 (or bf16, ``accum``) VMEM
+accumulator is flushed to the output tile at the last K step. The matmul
+runs at ``Precision.HIGHEST``: the weight tile holds exact f32 codebook
+levels, and a single bf16 pass would round them.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -27,18 +21,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.icq_dequant import (
     _codebook_select,
-    _decode_block_selector,
-    _pad2,
-    _round_up,
-    _unpack_block,
-    check_onehot,
-    column_granularity,
-    snap_block_k,
-)
-from repro.kernels.platform import (
-    default_accum_dtype,
-    default_interpret,
-    default_onehot_dtype,
+    _tile_selector,
+    _unpack_planes,
+    rel_scratch,
 )
 
 
@@ -53,245 +38,137 @@ def accum_scratch_dtype(accum: str):
     return jnp.float32 if accum == "f32" else jnp.bfloat16
 
 
-def _matmul_kernel(x_ref, codes_ref, bitmap_ref, cb_ref, out_ref, acc_ref,
-                   *, n_bits: int, n_k: int, onehot: str):
+def _accumulate(x_ref, wt, out_ref, acc_ref, n_k: int):
     @pl.when(pl.program_id(2) == 0)
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    BK = x_ref.shape[-1]
-    codes = _unpack_block(codes_ref[...], n_bits, BK)     # (BN, BK)
-    sel = _unpack_block(bitmap_ref[...], 1, BK)
-    w = _codebook_select(sel * (1 << n_bits) + codes, cb_ref[...], onehot)
     acc_ref[...] += jax.lax.dot_general(
-        x_ref[...].astype(jnp.float32), w,
-        (((1,), (1,)), ((), ())),                          # x @ w.T
+        x_ref[...].astype(jnp.float32), wt,
+        (((1,), (0,)), ((), ())),                              # x @ W^T
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
-    ).astype(acc_ref.dtype)            # MXU still f32; bf16 rounds per step
+    ).astype(acc_ref.dtype)
 
     @pl.when(pl.program_id(2) == n_k - 1)
     def _flush():
         out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
 
+def _matmul_kernel(x_ref, codes_ref, bitmap_ref, cb_ref, out_ref, acc_ref,
+                   *, n_bits: int, n_k: int):
+    sel = _unpack_planes(bitmap_ref[...], 1)
+    idx = (sel << n_bits) + _unpack_planes(codes_ref[...], n_bits)
+    _accumulate(x_ref, _codebook_select(idx, cb_ref[...]), out_ref, acc_ref,
+                n_k)
+
+
+def _matmul_kernel_v2(x_ref, codes_ref, syms_ref, offs_ref, dbase_ref,
+                      cb_ref, out_ref, acc_ref, rel_ref, *, n_bits: int,
+                      b: int, n_k: int):
+    BK = x_ref.shape[-1]
+    sel = _tile_selector(syms_ref[...], offs_ref[...], dbase_ref[...],
+                         pl.program_id(2), rel_ref, b=b, block_k=BK)
+    idx = (sel << n_bits) + _unpack_planes(codes_ref[...], n_bits)
+    _accumulate(x_ref, _codebook_select(idx, cb_ref[...]), out_ref, acc_ref,
+                n_k)
+
+
+def _fixed(rows: int, block_n: int):
+    return pl.BlockSpec((rows, block_n), lambda i, j, kk: (0, j))
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("n_bits", "block_m", "block_n", "block_k", "interpret",
-                     "onehot", "accum"),
+                     "accum"),
 )
 def matmul_padded(
     x: jnp.ndarray,          # (pm, pk) f32, pm % block_m == pk % block_k == 0
-    codes: jnp.ndarray,      # (pn, pk // k) uint32, pn % block_n == 0
-    bitmap: jnp.ndarray,     # (pn, pk // 32) uint32
-    codebooks: jnp.ndarray,  # (pn, C) f32
+    codes: jnp.ndarray,      # (n*pk/32, pn) uint32 bit planes
+    bitmap: jnp.ndarray,     # (pk/32, pn) uint32 selector plane
+    codebooks: jnp.ndarray,  # (C, pn)
     *,
     n_bits: int,
     block_m: int,
     block_n: int,
     block_k: int,
     interpret: bool,
-    onehot: str = "f32",
     accum: str = "f32",
 ) -> jnp.ndarray:
-    """Core fused kernel over pre-blocked inputs -> (pm, pn) f32 (padded)."""
-    check_onehot(onehot)
+    """v1 fused core over prepared inputs -> (pm, pn) f32 (padded)."""
     check_accum(accum)
-    k = 32 // n_bits
     pm, pk = x.shape
-    pn = codes.shape[0]
-    C = codebooks.shape[1]
+    pn = codes.shape[1]
     grid = (pm // block_m, pn // block_n, pk // block_k)
     return pl.pallas_call(
-        functools.partial(_matmul_kernel, n_bits=n_bits, n_k=grid[2],
-                          onehot=onehot),
+        functools.partial(_matmul_kernel, n_bits=n_bits, n_k=grid[2]),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((block_n, block_k // k), lambda i, j, kk: (j, kk)),
-            pl.BlockSpec((block_n, block_k // 32), lambda i, j, kk: (j, kk)),
-            pl.BlockSpec((block_n, C), lambda i, j, kk: (j, 0)),
+            pl.BlockSpec((n_bits * block_k // 32, block_n),
+                         lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((block_k // 32, block_n), lambda i, j, kk: (kk, j)),
+            _fixed(codebooks.shape[0], block_n),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((pm, pn), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n),
                                    accum_scratch_dtype(accum))],
         interpret=interpret,
+        name="icq_matmul_v1",
     )(x, codes, bitmap, codebooks)
-
-
-def _matmul_kernel_v2(x_ref, codes_ref, syms_ref, offs_ref, dbase_ref,
-                      cb_ref, out_ref, acc_ref, *, n_bits: int, b: int,
-                      n_k: int, onehot: str):
-    @pl.when(pl.program_id(2) == 0)
-    def _zero():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    BK = x_ref.shape[-1]
-    codes = _unpack_block(codes_ref[...], n_bits, BK)          # (BN, BK)
-    sel = _decode_block_selector(
-        syms_ref[...], offs_ref[...], dbase_ref[...], pl.program_id(2),
-        b=b, block_k=BK,
-    )
-    w = _codebook_select(sel * (1 << n_bits) + codes, cb_ref[...], onehot)
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...].astype(jnp.float32), w,
-        (((1,), (1,)), ((), ())),                              # x @ w.T
-        preferred_element_type=jnp.float32,
-    ).astype(acc_ref.dtype)            # MXU still f32; bf16 rounds per step
-
-    @pl.when(pl.program_id(2) == n_k - 1)
-    def _flush():
-        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("n_bits", "b", "block_m", "block_n", "interpret",
-                     "onehot", "accum"),
+                     "accum"),
 )
 def matmul_padded_v2(
     x: jnp.ndarray,          # (pm, pk) f32, pm % block_m == 0
-    codes: jnp.ndarray,      # (pn, pk // k) uint32, pn % block_n == 0
-    syms: jnp.ndarray,       # (pn, SW) uint32 packed b-bit gap symbols
-    offs: jnp.ndarray,       # (pn, T+1) uint16 tile symbol offsets
-    dbase: jnp.ndarray,      # (pn, T) uint8/uint16 tile base deltas
-    codebooks: jnp.ndarray,  # (pn, C)
+    codes: jnp.ndarray,      # (n*pk/32, pn) uint32 bit planes
+    syms: jnp.ndarray,       # (SW, pn) uint32 packed gap symbols
+    offs: jnp.ndarray,       # (T+1, pn) uint16 tile symbol offsets
+    dbase: jnp.ndarray,      # (T, pn) uint8/uint16 tile base deltas
+    codebooks: jnp.ndarray,  # (C, pn)
     *,
     n_bits: int,
     b: int,
     block_m: int,
     block_n: int,
     interpret: bool,
-    onehot: str = "f32",
     accum: str = "f32",
 ) -> jnp.ndarray:
-    """v2 fused core over pre-blocked inputs -> (pm, pn) f32 (padded).
+    """v2 fused core over prepared inputs -> (pm, pn) f32 (padded).
 
     block_k is the checkpoint tile (pk / T from the sidecar shape); the
     selector never exists as a bitmap in HBM — each K block decodes its
     own tile of the gap stream in VMEM.
     """
-    check_onehot(onehot)
     check_accum(accum)
-    k = 32 // n_bits
     pm, pk = x.shape
-    pn = codes.shape[0]
-    C = codebooks.shape[1]
-    T = offs.shape[1] - 1
+    pn = codes.shape[1]
+    T = offs.shape[0] - 1
     block_k = pk // T
-    SW = syms.shape[1]
-    grid = (pm // block_m, pn // block_n, T)
+    SW = syms.shape[0]
     return pl.pallas_call(
-        functools.partial(_matmul_kernel_v2, n_bits=n_bits, b=b, n_k=T,
-                          onehot=onehot),
-        grid=grid,
+        functools.partial(_matmul_kernel_v2, n_bits=n_bits, b=b, n_k=T),
+        grid=(pm // block_m, pn // block_n, T),
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((block_n, block_k // k), lambda i, j, kk: (j, kk)),
-            pl.BlockSpec((block_n, SW), lambda i, j, kk: (j, 0)),
-            pl.BlockSpec((block_n, T + 1), lambda i, j, kk: (j, 0)),
-            pl.BlockSpec((block_n, T), lambda i, j, kk: (j, 0)),
-            pl.BlockSpec((block_n, C), lambda i, j, kk: (j, 0)),
+            pl.BlockSpec((n_bits * block_k // 32, block_n),
+                         lambda i, j, kk: (kk, j)),
+            _fixed(SW, block_n),
+            _fixed(T + 1, block_n),
+            _fixed(T, block_n),
+            _fixed(codebooks.shape[0], block_n),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((pm, pn), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n),
-                                   accum_scratch_dtype(accum))],
+                                   accum_scratch_dtype(accum)),
+                        rel_scratch(SW, b, block_n)],
         interpret=interpret,
+        name="icq_matmul_v2",
     )(x, codes, syms, offs, dbase, codebooks)
-
-
-def icq_matmul_v2(
-    x: jnp.ndarray,          # (M, d_in)
-    codes: jnp.ndarray,      # (d_out, Wc) uint32
-    syms: jnp.ndarray,       # (d_out, SW) uint32
-    offs: jnp.ndarray,       # (d_out, T+1) uint16
-    dbase: jnp.ndarray,      # (d_out, T) uint8/uint16
-    codebooks: jnp.ndarray,  # (d_out, 2^(n+1))
-    *,
-    n_bits: int,
-    b: int,
-    d_in: int,
-    tile: int,
-    block_m: int = 128,
-    block_n: int = 128,
-    interpret: Optional[bool] = None,
-    onehot: Optional[str] = None,
-    accum: Optional[str] = None,
-) -> jnp.ndarray:
-    """Pad-on-the-fly v2 wrapper -> (M, d_out) f32."""
-    if interpret is None:
-        interpret = default_interpret()
-    if onehot is None:
-        onehot = default_onehot_dtype()
-    if accum is None:
-        accum = default_accum_dtype()
-    M = x.shape[0]
-    d_out = codes.shape[0]
-    k = 32 // n_bits
-    T = offs.shape[1] - 1
-    pk = T * tile
-    bm = min(block_m, _round_up(M, 8))
-    bn = min(block_n, _round_up(d_out, 8))
-    pm, pn = _round_up(M, bm), _round_up(d_out, bn)
-    out = matmul_padded_v2(
-        _pad2(x.astype(jnp.float32), pm, pk),
-        _pad2(codes, pn, pk // k),
-        _pad2(syms, pn, syms.shape[1]),
-        _pad2(offs, pn, offs.shape[1]),
-        _pad2(dbase, pn, dbase.shape[1]),
-        _pad2(codebooks, pn, codebooks.shape[1]),
-        n_bits=n_bits, b=b, block_m=bm, block_n=bn, interpret=interpret,
-        onehot=onehot, accum=accum,
-    )
-    return out[:M, :d_out]
-
-
-def matmul_blocks(M: int, d_out: int, d_in: int, n_bits: int,
-                  block_m: int, block_n: int, block_k: int,
-                  fmt: str = "v1"):
-    """Snap requested blocks to packing/tiling granularities -> (bm, bn, bk)."""
-    lcm = column_granularity(n_bits, fmt)
-    bm = min(block_m, _round_up(M, 8))
-    bn = min(block_n, _round_up(d_out, 8))
-    return bm, bn, snap_block_k(d_in, lcm, block_k)
-
-
-def icq_matmul(
-    x: jnp.ndarray,          # (M, d_in)
-    codes: jnp.ndarray,      # (d_out, Wc) uint32
-    bitmap: jnp.ndarray,     # (d_out, Wb) uint32
-    codebooks: jnp.ndarray,  # (d_out, 2^(n+1)) f32
-    *,
-    n_bits: int,
-    d_in: int,
-    block_m: int = 128,
-    block_n: int = 128,
-    block_k: int = 512,
-    interpret: Optional[bool] = None,
-    onehot: Optional[str] = None,
-    accum: Optional[str] = None,
-) -> jnp.ndarray:
-    """Pad-on-the-fly wrapper -> (M, d_out) f32."""
-    if interpret is None:
-        interpret = default_interpret()
-    if onehot is None:
-        onehot = default_onehot_dtype()
-    if accum is None:
-        accum = default_accum_dtype()
-    M = x.shape[0]
-    d_out = codes.shape[0]
-    k = 32 // n_bits
-    bm, bn, bk = matmul_blocks(M, d_out, d_in, n_bits,
-                               block_m, block_n, block_k)
-    pm, pk_, pn = _round_up(M, bm), _round_up(d_in, bk), _round_up(d_out, bn)
-    x_p = _pad2(x.astype(jnp.float32), pm, pk_)
-    codes_p = _pad2(codes, pn, pk_ // k)
-    bitmap_p = _pad2(bitmap, pn, pk_ // 32)
-    cb_p = _pad2(codebooks, pn, codebooks.shape[1])
-    out = matmul_padded(
-        x_p, codes_p, bitmap_p, cb_p,
-        n_bits=n_bits, block_m=bm, block_n=bn, block_k=bk,
-        interpret=interpret, onehot=onehot, accum=accum,
-    )
-    return out[:M, :d_out]

@@ -1,4 +1,4 @@
-"""Jit'd public wrappers around the Pallas kernels + format conversion.
+"""Public wrappers around the Pallas kernels + format conversion.
 
 ``to_runtime(packed, fmt=...)`` converts an ICQPacked (storage format:
 n-bit codes + ~0.31 b/w gap stream) into a kernel runtime dict. Two
@@ -24,6 +24,10 @@ model-load time; see kernels/backend.py for the prepared (pre-padded,
 pre-blocked) layout the execution layer serves from, and
 ``ICQ_RUNTIME_FMT`` for the global format override.
 
+The runtime dicts keep the row-major storage packing; ``dequant`` and
+``matmul`` convert a dict into the kernel layout (``backend.prepare``)
+on every call, so they suit tests and one-off calls, not a hot loop.
+
 ``interpret`` defaults to None everywhere = platform-autodetected
 (compiled on TPU, interpreter off-TPU; kernels/platform.py) — callers
 never pass it explicitly anymore.
@@ -43,20 +47,16 @@ from repro.core.index_coding import decode_to_dense_mask, stream_checkpoints
 from repro.kernels.backend import (
     ICQPrepared,
     WeightIntegrityError,
+    _dequant_t,
+    _fused,
+    _round_up,
     dequantize_prepared,
     linear_apply,
     prepare,
     prepare_tree,
+    snap_block_k,
     verify_runtime_integrity,
 )
-from repro.kernels.icq_dequant import (
-    _round_up,
-    column_granularity,
-    icq_dequant,
-    icq_dequant_v2,
-    snap_block_k,
-)
-from repro.kernels.icq_matmul import icq_matmul, icq_matmul_v2
 from repro.kernels.kmeans_assign import kmeans_assign
 
 _CB_DTYPES = {None: jnp.float32, "f32": jnp.float32, "bf16": jnp.bfloat16}
@@ -68,8 +68,8 @@ def to_runtime(packed: ICQPacked, fmt: str = "v1", *, tile: int = 512,
 
     fmt='v1': dense 1-bit selector bitmap (legacy bench/test format).
     fmt='v2': packed b-bit gap symbols + per-``tile`` checkpoints
-              (``tile`` is snapped to the code-packing granularity and
-              becomes the kernels' column block).
+              (``tile`` is snapped to a multiple of 256 dividing the
+              padded row and becomes the kernels' K block).
     """
     assert packed.codes.ndim == 2, "expand stacked weights per slice"
     if codebook_dtype not in _CB_DTYPES:
@@ -84,8 +84,7 @@ def to_runtime(packed: ICQPacked, fmt: str = "v1", *, tile: int = 512,
         return dict(common, fmt="v1", bitmap=packing.pack_codes(sel, 1))
     if fmt != "v2":
         raise ValueError(f"fmt must be 'v1' or 'v2', got {fmt!r}")
-    tile = snap_block_k(packed.d_in, column_granularity(packed.n_bits, "v2"),
-                        tile)
+    tile = snap_block_k(packed.d_in, tile)
     pk = _round_up(packed.d_in, tile)
     sym_np = np.asarray(jax.device_get(packed.symbols))
     cnt_np = np.asarray(jax.device_get(packed.counts))
@@ -137,42 +136,37 @@ def runtime_outlier_bits_per_weight(rt: Dict) -> float:
     return total / (d_out * rt["d_in"])
 
 
-def _check_blocks(blocks: Dict, allowed: tuple, fmt: str) -> None:
-    bad = set(blocks) - set(allowed)
-    if bad:
+def _prepared(rt: Dict, interpret, block_n, block_k, block_m=8):
+    """Kernel layout of a runtime dict with the requested blocks (the
+    v2 K block is the dict's checkpoint tile)."""
+    if block_k is not None and rt.get("fmt", "v1") == "v2":
         raise TypeError(
-            f"block kwargs {sorted(bad)} do not apply to the {fmt} runtime "
-            f"format (its column block is the checkpoint tile); "
-            f"allowed: {sorted(allowed)}")
+            "block_k does not apply to the v2 runtime format (its K block "
+            "is the checkpoint tile)")
+    blocks = (block_m, block_n, 512 if block_k is None else block_k)
+    return prepare(rt, blocks=blocks, backend="pallas", interpret=interpret)
 
 
-def dequant(rt: Dict, interpret: Optional[bool] = None, **blocks) -> jnp.ndarray:
-    if rt.get("fmt", "v1") == "v2":
-        _check_blocks(blocks, ("block_r", "onehot"), "v2")
-        return icq_dequant_v2(
-            rt["codes"], rt["syms"], rt["offs"], rt["dbase"], rt["codebooks"],
-            n_bits=rt["n_bits"], b=rt["b"], d_in=rt["d_in"], tile=rt["tile"],
-            interpret=interpret, **blocks,
-        )
-    return icq_dequant(
-        rt["codes"], rt["bitmap"], rt["codebooks"],
-        n_bits=rt["n_bits"], d_in=rt["d_in"], interpret=interpret, **blocks
-    )
+def dequant(rt: Dict, interpret: Optional[bool] = None, *,
+            block_n: int = 128, block_k: Optional[int] = None) -> jnp.ndarray:
+    """Dequant kernel on a runtime dict -> (d_out, d_in) f32."""
+    prep = _prepared(rt, interpret, block_n, block_k)
+    wt = _dequant_t(prep, prep.block_n, prep.block_k)
+    return jnp.swapaxes(wt[: prep.d_in, : prep.d_out], 0, 1)
 
 
-def matmul(x, rt: Dict, interpret: Optional[bool] = None, **blocks) -> jnp.ndarray:
-    if rt.get("fmt", "v1") == "v2":
-        _check_blocks(blocks, ("block_m", "block_n", "onehot", "accum"), "v2")
-        return icq_matmul_v2(
-            x, rt["codes"], rt["syms"], rt["offs"], rt["dbase"],
-            rt["codebooks"],
-            n_bits=rt["n_bits"], b=rt["b"], d_in=rt["d_in"], tile=rt["tile"],
-            interpret=interpret, **blocks,
-        )
-    return icq_matmul(
-        x, rt["codes"], rt["bitmap"], rt["codebooks"],
-        n_bits=rt["n_bits"], d_in=rt["d_in"], interpret=interpret, **blocks
-    )
+def matmul(x, rt: Dict, interpret: Optional[bool] = None, *,
+           block_m: int = 128, block_n: int = 128,
+           block_k: Optional[int] = None,
+           accum: Optional[str] = None) -> jnp.ndarray:
+    """Fused kernel on a runtime dict: (M, d_in) -> (M, d_out) f32."""
+    prep = _prepared(rt, interpret, block_n, block_k, block_m)
+    M = x.shape[0]
+    bm = min(prep.block_m, _round_up(M, 8))
+    x_p = jnp.pad(x.astype(jnp.float32),
+                  ((0, _round_up(M, bm) - M), (0, prep.pk - prep.d_in)))
+    y = _fused(x_p, prep, bm, prep.block_n, prep.block_k, accum)
+    return y[:M, : prep.d_out]
 
 
 __all__ = ["to_runtime", "runtime_bits_per_weight",

@@ -1,7 +1,8 @@
 """Pallas TPU kernel: paged-attention for S=1 decode over a block-pool KV.
 
 The paged KV layout (serving/kv_pool.py) stores cache rows in a global
-block pool ``(num_blocks, block_size, ...)`` with per-lane page tables
+head-major block pool ``(num_blocks, n_heads, block_size, head_dim)``
+with per-lane page tables
 ``(B, n_pt)`` int32 (-1 = unmapped). The XLA serving arm
 (``models/layers.py::_paged_gather``) materializes the full logical
 ``(B, n_pt * block_size)`` view every step — reading every
@@ -27,7 +28,8 @@ semantics, Kwon et al.):
 One kernel serves both paged attention flavors:
 
   * **GQA** — q ``(B, Hkv, G, hd)`` (pre-scaled by the caller), K/V
-    pools ``(nb, bs, Hkv, hd)``;
+    pools ``(nb, Hkv, bs, hd)``: one grid step's page of one head is a
+    contiguous ``(bs, hd)`` tile, read once;
   * **MLA latent cache** — the absorbed decode attends over the latent
     ``c_kv`` stream with a rope side-channel: pass the rope halves as
     ``q2``/``k2_pool`` (scores add) and the ``c_kv`` pool as *both* K
@@ -50,6 +52,9 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.platform import default_interpret
 
 _NEG = -1e30  # python float: a jnp constant would be captured by the kernel
+# f32 pools stay f32 on the MXU (the default precision rounds operands to
+# bf16: ~1e-2 absolute on unit-scale values); free for bf16 pools
+_HI = jax.lax.Precision.HIGHEST
 
 #: pages-per-grid-step candidates for the autotune sweep, largest first
 PAGES_PER_STEP_CANDIDATES = (8, 4, 2, 1)
@@ -71,7 +76,7 @@ def _pool_index_map(i: int, P: int):
         last = jnp.maximum(nblk_ref[b] - 1, 0)
         blk = jnp.minimum(j * P + i, last)
         page = jnp.maximum(pages_ref[b, blk], 0)   # -1 unmapped -> block 0
-        return (page, 0, h, 0)
+        return (page, h, 0, 0)
     return index_map
 
 
@@ -111,12 +116,12 @@ def _paged_attn_kernel(pages_ref, nblk_ref, len_ref, *refs,
             k = k_refs[i][...].astype(jnp.float32)           # (bs, d)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),              # q @ k.T
-                preferred_element_type=jnp.float32)          # (G, bs)
+                precision=_HI, preferred_element_type=jnp.float32)
             if has_q2:
                 k2 = k2_refs[i][...].astype(jnp.float32)
                 s = s + jax.lax.dot_general(
                     q2, k2, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
+                    precision=_HI, preferred_element_type=jnp.float32)
             # mask rows past the lane's live length (partial tail block)
             pos = blk * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(pos < len_ref[b], s, _NEG)
@@ -128,7 +133,7 @@ def _paged_attn_kernel(pages_ref, nblk_ref, len_ref, *refs,
             v = v_refs[i][...].astype(jnp.float32)           # (bs, dv)
             acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
                 p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                precision=_HI, preferred_element_type=jnp.float32)
             l_ref[...] = jnp.broadcast_to(
                 alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True),
                 l_ref.shape)
@@ -145,7 +150,7 @@ def _paged_attn_kernel(pages_ref, nblk_ref, len_ref, *refs,
 def _paged_attention_call(q, k_pool, v_pool, q2, k2_pool, pages, nblk,
                           kv_len, *, pages_per_step: int, interpret: bool):
     B, Hkv, G, d = q.shape
-    nb, bs = k_pool.shape[0], k_pool.shape[1]
+    bs = k_pool.shape[2]
     dv = v_pool.shape[-1]
     n_pt = pages.shape[1]
     P = max(1, min(pages_per_step, n_pt))
@@ -161,16 +166,16 @@ def _paged_attention_call(q, k_pool, v_pool, q2, k2_pool, pages, nblk,
         in_specs.append(_fixed((None, None, G, q2.shape[-1])))
         operands.append(q2)
     for i in range(P):                                       # K pages
-        in_specs.append(pl.BlockSpec((None, bs, None, d),
+        in_specs.append(pl.BlockSpec((None, None, bs, d),
                                      _pool_index_map(i, P)))
         operands.append(k_pool)
     if has_q2:
         for i in range(P):                                   # rope K pages
-            in_specs.append(pl.BlockSpec((None, bs, None, k2_pool.shape[-1]),
+            in_specs.append(pl.BlockSpec((None, None, bs, k2_pool.shape[-1]),
                                          _pool_index_map(i, P)))
             operands.append(k2_pool)
     for i in range(P):                                       # V pages
-        in_specs.append(pl.BlockSpec((None, bs, None, dv),
+        in_specs.append(pl.BlockSpec((None, None, bs, dv),
                                      _pool_index_map(i, P)))
         operands.append(v_pool)
 
@@ -192,6 +197,7 @@ def _paged_attention_call(q, k_pool, v_pool, q2, k2_pool, pages, nblk,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, dv), jnp.float32),
         interpret=interpret,
+        name="paged_attention",
     )(pages, nblk, kv_len, *operands)
 
 
@@ -222,13 +228,13 @@ def fallback_pages_per_step(*, G: int, d: int, dv: int, bs: int, n_pt: int,
 
 def paged_attention(
     q: jnp.ndarray,                     # (B, Hkv, G, d), pre-scaled
-    k_pool: jnp.ndarray,                # (nb, bs, Hkv, d)
-    v_pool: jnp.ndarray,                # (nb, bs, Hkv, dv)
+    k_pool: jnp.ndarray,                # (nb, Hkv, bs, d)
+    v_pool: jnp.ndarray,                # (nb, Hkv, bs, dv)
     pages: jnp.ndarray,                 # (B, n_pt) int32, -1 = unmapped
     kv_len: jnp.ndarray,                # (B,) int32 live rows per lane
     *,
     q2: Optional[jnp.ndarray] = None,       # (B, Hkv, G, d2) rope half
-    k2_pool: Optional[jnp.ndarray] = None,  # (nb, bs, Hkv, d2)
+    k2_pool: Optional[jnp.ndarray] = None,  # (nb, Hkv, bs, d2)
     pages_per_step: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
@@ -246,7 +252,7 @@ def paged_attention(
     if (q2 is None) != (k2_pool is None):
         raise ValueError("q2 and k2_pool must be passed together")
     B, Hkv, G, d = q.shape
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     n_pt = pages.shape[1]
     if pages_per_step is None:
         pages_per_step = fallback_pages_per_step(

@@ -3,8 +3,8 @@
 Central policy knob for every Pallas entry point in this package:
 
   * ``detected_platform()``  — jax default backend ('tpu' | 'cpu' | 'gpu'),
-    overridable with ``ICQ_PLATFORM`` (useful for forcing the TPU code
-    path through eval_shape-style lowering tests on CPU).
+    overridable with ``ICQ_PLATFORM``. A backend that fails to
+    initialize raises: nothing here guesses a platform.
   * ``default_interpret()``  — Pallas kernels compile natively on TPU and
     fall back to ``interpret=True`` everywhere else. ``ICQ_INTERPRET=0/1``
     forces either mode.
@@ -20,12 +20,6 @@ Central policy knob for every Pallas entry point in this package:
     'v2' (checkpointed gap stream, ~0.3-0.45 b/w outlier overhead) by
     default, 'v1' (dense 1-bit selector bitmap, ~1 b/w) as the
     bitwise-parity fallback. ``ICQ_RUNTIME_FMT=v1|v2`` overrides.
-  * ``default_onehot_dtype()`` — dtype of the (BR, BC, C) one-hot
-    codebook-select temporary inside both Pallas kernels: 'f32'
-    (default, exact) or 'bf16' (halves the dominant VMEM term, so the
-    autotuner can admit larger prefill blocks under ICQ_VMEM_BUDGET_MB;
-    codebook levels round to bf16 — ~3 decimal digits).
-    ``ICQ_ONEHOT_DTYPE=f32|bf16`` overrides.
   * ``default_accum_dtype()`` — dtype of the fused matmul kernels'
     VMEM accumulator scratch: 'f32' (default, exact) or 'bf16' (halves
     the accumulator VMEM term; partial sums round to bf16 per K-step).
@@ -51,10 +45,7 @@ def detected_platform() -> str:
     override = os.environ.get("ICQ_PLATFORM")
     if override:
         return override.lower()
-    try:
-        return jax.default_backend()
-    except Exception:  # backend init failure: assume portable host
-        return "cpu"
+    return jax.default_backend()
 
 
 def default_interpret() -> bool:
@@ -91,18 +82,6 @@ def default_runtime_fmt() -> str:
                 f"ICQ_RUNTIME_FMT must be 'v1' or 'v2', got {env!r}")
         return env
     return "v2"
-
-
-def default_onehot_dtype() -> str:
-    """'f32' (exact) or 'bf16' (half-size one-hot select temporary)."""
-    env = os.environ.get("ICQ_ONEHOT_DTYPE")
-    if not env:  # unset or set-but-empty
-        return "f32"
-    env = env.lower()
-    if env not in ("f32", "bf16"):
-        raise ValueError(
-            f"ICQ_ONEHOT_DTYPE must be 'f32' or 'bf16', got {env!r}")
-    return env
 
 
 def default_accum_dtype() -> str:

@@ -10,15 +10,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType`` itself) only exist in newer releases, and
-    Auto is the default there anyway — so fall back to plain make_mesh
-    on older jax instead of crashing every driver at import-of-use."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis Auto (sharded by propagation)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -76,16 +76,15 @@ def quantize_tree(
         fw = fisher_flat[i] if fisher_flat is not None else None
         lead = leaf.shape[:-2]
         d_in, d_out = leaf.shape[-2], leaf.shape[-1]
-        # per output channel = rows of W^T
-        mats = jnp.moveaxis(leaf, -1, -2).reshape(-1, d_out, d_in)
-        fmats = (
-            None if fw is None
-            else jnp.moveaxis(fw, -1, -2).reshape(-1, d_out, d_in)
-        )
+        # per output channel = rows of W^T, transposed one slice at a
+        # time (a whole-stack transpose would double the stack's memory)
+        mats = leaf.reshape(-1, d_in, d_out)
+        fmats = None if fw is None else fw.reshape(-1, d_in, d_out)
         packs = [
             icquant.quantize(
-                mats[j], n_bits, gamma=gamma, b=b, method=method,
-                fisher=None if fmats is None else normalize_fisher(fmats[j]),
+                mats[j].T, n_bits, gamma=gamma, b=b, method=method,
+                fisher=None if fmats is None
+                else normalize_fisher(fmats[j].T),
             )
             for j in range(mats.shape[0])
         ]

@@ -12,11 +12,14 @@ warning (``--strict-len`` rejects those too instead of truncating).
 from __future__ import annotations
 
 import argparse
+import os
 import signal
+import tempfile
 
 import numpy as np
 
 from repro.configs import get_config, smoke_variant
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.quantize import quantize_tree
 from repro.launch.train import train
 from repro.serving import GenerationEngine, Request, SamplingParams
@@ -205,6 +208,40 @@ def _serve_replicas(args, params, cfg, sampling):
     print(f"[serve] statuses: {statuses or 'none'}")
 
 
+def serving_config(arch: str, full: bool = False):
+    """The config ``serve`` runs: published widths with ``full``, else the
+    smoke variant; frontends are stripped (requests carry token ids)."""
+    cfg = get_config(arch)
+    if not full:
+        cfg = smoke_variant(cfg)
+    if cfg.is_encdec or cfg.frontend != "none":
+        import dataclasses
+        cfg = dataclasses.replace(cfg, frontend="none", frontend_len=0)
+    return cfg
+
+
+def load_params(arch: str, *, full: bool = False, train_steps: int = 10,
+                seed: int = 0, bits: int = 0, gamma: float = 0.05):
+    """(cfg, params) to serve. ``train_steps > 0`` trains the smoke model
+    briefly; 0 draws seeded weights with ``init_model`` and never builds
+    optimizer state. ``bits`` > 0 quantizes with ICQuant (RTN)."""
+    import jax
+
+    from repro.models import init_model
+
+    cfg = serving_config(arch, full)
+    if train_steps:
+        params, _ = train(arch, steps=train_steps, batch=4, seq=64,
+                          ckpt_dir=os.path.join(tempfile.gettempdir(),
+                                                "repro_serve_ckpt"))
+    else:
+        params = init_model(jax.random.PRNGKey(seed), cfg)
+    if bits:
+        params, acct = quantize_tree(params, bits, gamma=gamma)
+        print(f"[serve] quantized to {acct['mean_bits']:.2f} bits/weight")
+    return cfg, params
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -353,7 +390,15 @@ def main():
     ap.add_argument("--top-k", type=int, default=0)
     ap.add_argument("--top-p", type=float, default=1.0)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--train-steps", type=int, default=10)
+    ap.add_argument("--train-steps", type=int, default=10,
+                    help="train the smoke model this many steps before "
+                         "serving; 0 serves seeded random weights "
+                         "(init_model(PRNGKey(seed))) and builds no "
+                         "optimizer state")
+    ap.add_argument("--full", action="store_true",
+                    help="serve the published (non-smoke) config; needs "
+                         "--train-steps 0 (training at full width would "
+                         "not fit one chip)")
     ap.add_argument("--weight-cache", default="prepared",
                     choices=["prepared", "dense", "none"],
                     help="load-time ICQ weight conversion: 'prepared' = "
@@ -373,16 +418,12 @@ def main():
     if args.prefix_cache and args.kv_layout is None:
         args.kv_layout = "paged"
 
-    cfg = smoke_variant(get_config(args.arch))
-    if cfg.is_encdec or cfg.frontend != "none":
-        import dataclasses
-        cfg = dataclasses.replace(cfg, frontend="none", frontend_len=0)
-
-    params, _ = train(args.arch, steps=args.train_steps, batch=4, seq=64,
-                      ckpt_dir="/tmp/repro_serve_ckpt")
-    if args.bits:
-        params, acct = quantize_tree(params, args.bits, gamma=args.gamma)
-        print(f"[serve] quantized to {acct['mean_bits']:.2f} bits/weight")
+    if args.full and args.train_steps:
+        ap.error("--full serves seeded weights: pass --train-steps 0")
+    use_compile_cache()
+    cfg, params = load_params(args.arch, full=args.full,
+                              train_steps=args.train_steps, seed=args.seed,
+                              bits=args.bits, gamma=args.gamma)
 
     sampling = SamplingParams(temperature=args.temperature,
                               top_k=args.top_k, top_p=args.top_p)

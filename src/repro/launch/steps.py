@@ -192,7 +192,7 @@ def fork_cache_block(cache, src, dst):
     if isinstance(cache, dict):
         if "pages" in cache:
             # pages is (..., B, n_pt); the pool leaves share its leading
-            # layer-stack axes, then (num_blocks, block_size, ...)
+            # layer-stack axes, then (num_blocks, heads, block_size, d)
             lead = (slice(None),) * (cache["pages"].ndim - 2)
             return {
                 k: (v if k in ("index", "pages")

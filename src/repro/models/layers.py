@@ -205,9 +205,11 @@ def _check_seq_lens(seq_lens, cache) -> None:
 
 def _paged_scatter(pool: jnp.ndarray, pages: jnp.ndarray, cols: jnp.ndarray,
                    values: jnp.ndarray) -> jnp.ndarray:
-    """Write ``values`` (B, S, ...) into a block pool through a page table.
+    """Write ``values`` (B, S, H, d) into a block pool through a page table.
 
-    ``pool`` is (num_blocks, block_size, ...); ``pages`` (B, n_pt) maps
+    ``pool`` is head-major (num_blocks, H, block_size, d), so the paged
+    attention kernel reads one head's page as a contiguous
+    (block_size, d) tile; ``pages`` (B, n_pt) maps
     each lane's logical block j to a physical block (-1 = unmapped);
     ``cols`` (B, S) holds logical positions with invalid entries already
     pushed to ``n_pt * block_size`` by ``_chunk_write_cols``. Invalid
@@ -215,27 +217,27 @@ def _paged_scatter(pool: jnp.ndarray, pages: jnp.ndarray, cols: jnp.ndarray,
     which the ``mode='drop'`` scatter discards — mirroring the
     contiguous path's out-of-range-write semantics exactly.
     """
-    nb, bs = pool.shape[0], pool.shape[1]
+    nb, bs = pool.shape[0], pool.shape[2]
     n_pt = pages.shape[1]
     blk = jnp.take_along_axis(
         pages, jnp.clip(cols // bs, 0, n_pt - 1), axis=1)       # (B, S)
     ok = (cols < n_pt * bs) & (blk >= 0)
     blk = jnp.where(ok, blk, nb)                                # -> dropped
     off = jnp.where(ok, cols % bs, 0)
-    return pool.at[blk, off].set(values.astype(pool.dtype), mode="drop")
+    return pool.at[blk, :, off].set(values.astype(pool.dtype), mode="drop")
 
 
 def _paged_gather(pool: jnp.ndarray, pages: jnp.ndarray) -> jnp.ndarray:
-    """Read each lane's logical KV view (B, n_pt * block_size, ...) out of
-    the block pool. Unmapped (-1) page entries clamp to block 0 — the
+    """Read each lane's logical KV view (B, n_pt * block_size, H, d) out of
+    the head-major block pool. Unmapped (-1) page entries clamp to block 0 — the
     gathered garbage sits at logical positions beyond the lane's write
     index, which the per-lane validity mask already excludes (a lane
     maps a block before the first write into it, and position ``p`` is
     written in the same step it first becomes valid)."""
-    nb, bs = pool.shape[0], pool.shape[1]
+    nb, H, bs, d = pool.shape
     B, n_pt = pages.shape
-    out = pool[jnp.clip(pages, 0, nb - 1)]          # (B, n_pt, bs, ...)
-    return out.reshape((B, n_pt * bs) + pool.shape[2:])
+    out = pool[jnp.clip(pages, 0, nb - 1)]          # (B, n_pt, H, bs, d)
+    return jnp.swapaxes(out, 2, 3).reshape(B, n_pt * bs, H, d)
 
 
 def _paged_attn_arm(S: int, window: int, T: int) -> str:
@@ -352,7 +354,7 @@ def gqa_apply(
         # tests/test_kv_pool.py).
         idx = cache["index"]                        # (B,) per-lane
         pages = cache["pages"]                      # (B, n_pt), -1 unmapped
-        T = pages.shape[1] * cache["k"].shape[1]    # logical capacity
+        T = pages.shape[1] * cache["k"].shape[2]    # logical capacity
         cols = _chunk_write_cols(idx, S, T, seq_lens)
         ck = _paged_scatter(cache["k"], pages, cols, k)
         cv = _paged_scatter(cache["v"], pages, cols, v)
@@ -365,7 +367,7 @@ def gqa_apply(
             from repro.kernels.paged_attention import paged_attention
             Hkv = cfg.n_kv_heads
             G = cfg.n_heads // Hkv
-            bs = cache["k"].shape[1]
+            bs = cache["k"].shape[2]
             qk = (q[:, 0].astype(jnp.float32) * hd**-0.5
                   ).reshape(B, Hkv, G, hd)
             pps = _paged_pages_per_step(
@@ -461,8 +463,8 @@ def gqa_cache_init(cfg, batch: int, max_len: int,
         nb, bs = paged
         n_pt = -(-max_len // bs)
         return dict(
-            k=jnp.zeros((nb, bs, cfg.n_kv_heads, hd), dt),
-            v=jnp.zeros((nb, bs, cfg.n_kv_heads, hd), dt),
+            k=jnp.zeros((nb, cfg.n_kv_heads, bs, hd), dt),
+            v=jnp.zeros((nb, cfg.n_kv_heads, bs, hd), dt),
             index=jnp.zeros((batch,), jnp.int32),
             pages=jnp.full((batch, n_pt), -1, jnp.int32),
         )
@@ -552,15 +554,15 @@ def mla_apply(
     idx = cache["index"]  # int32 #tokens cached: scalar, or (B,) per-lane
     pages = cache.get("pages")      # paged latent cache (see gqa_apply)
     if pages is not None:
-        T = pages.shape[1] * cache["c_kv"].shape[1]
+        T = pages.shape[1] * cache["c_kv"].shape[2]
         cols = _chunk_write_cols(idx, S, T, seq_lens)
-        cc = _paged_scatter(cache["c_kv"], pages, cols, c_kv)
-        cr = _paged_scatter(cache["k_rope"], pages, cols, k_rope[:, :, 0, :])
+        cc = _paged_scatter(cache["c_kv"], pages, cols, c_kv[:, :, None])
+        cr = _paged_scatter(cache["k_rope"], pages, cols, k_rope)
         if _paged_attn_arm(S, 0, T) == "pallas":
             cc_log = cr_log = None      # in-kernel page walk, no gather
         else:
-            cc_log = _paged_gather(cc, pages)
-            cr_log = _paged_gather(cr, pages)
+            cc_log = _paged_gather(cc, pages)[:, :, 0]
+            cr_log = _paged_gather(cr, pages)[:, :, 0]
     elif idx.ndim:
         rows = jnp.arange(B, dtype=jnp.int32)[:, None]
         cols = _chunk_write_cols(idx, S, cache["c_kv"].shape[1], seq_lens)
@@ -588,16 +590,15 @@ def mla_apply(
         # pool doubles as K (latent half) and V; the rope side-channel
         # rides the kernel's q2/k2 score pair (Hkv=1, G=H).
         from repro.kernels.paged_attention import paged_attention
-        nb_, bs_ = cc.shape[0], cc.shape[1]
+        bs_ = cc.shape[2]
         qm = (q_lat[:, 0].astype(jnp.float32) * scale).reshape(B, 1, H, r)
         q2 = (q_rope[:, 0].astype(jnp.float32) * scale).reshape(B, 1, H, rd)
         pps = _paged_pages_per_step(
             G=H, d=r, dv=r, bs=bs_, n_pt=pages.shape[1], d2=rd,
             itemsize=cc.dtype.itemsize)
         ctx = paged_attention(
-            qm, cc.reshape(nb_, bs_, 1, r), cc.reshape(nb_, bs_, 1, r),
-            pages, idx + adv,
-            q2=q2, k2_pool=cr.reshape(nb_, bs_, 1, rd), pages_per_step=pps,
+            qm, cc, cc, pages, idx + adv, q2=q2, k2_pool=cr,
+            pages_per_step=pps,
         ).reshape(B, 1, H, r).astype(q_lat.dtype)                # (B,1,H,r)
     else:
         pos_k = jnp.broadcast_to(
@@ -627,8 +628,8 @@ def mla_cache_init(cfg, batch: int, max_len: int,
         nb, bs = paged
         n_pt = -(-max_len // bs)
         return dict(
-            c_kv=jnp.zeros((nb, bs, cfg.kv_lora_rank), dt),
-            k_rope=jnp.zeros((nb, bs, cfg.qk_rope_head_dim), dt),
+            c_kv=jnp.zeros((nb, 1, bs, cfg.kv_lora_rank), dt),
+            k_rope=jnp.zeros((nb, 1, bs, cfg.qk_rope_head_dim), dt),
             index=jnp.zeros((batch,), jnp.int32),
             pages=jnp.full((batch, n_pt), -1, jnp.int32),
         )

@@ -53,23 +53,14 @@ def compressed_cross_pod_mean(tree: Any, mesh, axis_name: str = "pod") -> Any:
     Must be called inside a computation already running under `mesh`;
     tensors keep their data/model shardings (auto axes)."""
     n_pods = dict(zip(mesh.axis_names, mesh.devices.shape))[axis_name]
-    other = frozenset(a for a in mesh.axis_names if a != axis_name)
 
     P = jax.sharding.PartitionSpec
-    if hasattr(jax, "shard_map"):
-        # the gathered+summed result is replicated over `pod` by
-        # construction; the static VMA checker can't prove it
-        smap = functools.partial(
-            jax.shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
-            axis_names={axis_name}, check_vma=False,
-        )
-    else:  # jax < 0.6: experimental spelling (auto axes / check_rep)
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        smap = functools.partial(
-            _shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
-            auto=other, check_rep=False,
-        )
+    # the gathered+summed result is replicated over `pod` by
+    # construction; the static VMA checker can't prove it
+    smap = functools.partial(
+        jax.shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
+        axis_names={axis_name}, check_vma=False,
+    )
 
     @smap
     def reduce_tree(t):

@@ -2,7 +2,7 @@
 
 The paged cache layout (``kv_layout='paged'``) replaces the contiguous
 per-lane ``(B, max_len, ...)`` KV regions with one global **block pool**
-per layer — ``(num_blocks, block_size, n_kv_heads, hd)`` — plus a
+per layer — head-major ``(num_blocks, n_kv_heads, block_size, hd)`` — plus a
 per-lane **page table** ``(B, max_blocks)`` mapping each lane's logical
 position range ``[j*block_size, (j+1)*block_size)`` to a physical block.
 Cache HBM is then ``num_blocks * block_size`` rows, decoupled from

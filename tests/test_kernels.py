@@ -22,7 +22,7 @@ def test_dequant_kernel_matches_ref(n_bits, shape):
     np.testing.assert_allclose(
         np.asarray(w_ref), np.asarray(core.dequantize(pk)), rtol=1e-6
     )
-    w_k = ops.dequant(rt, block_r=32, block_c=320)
+    w_k = ops.dequant(rt, block_n=128, block_k=256)
     np.testing.assert_allclose(np.asarray(w_k), np.asarray(w_ref), rtol=1e-6)
 
 
@@ -39,7 +39,7 @@ def test_matmul_kernel_matches_ref(n_bits, M, x_dtype):
     )
     y_ref = ref.matmul_ref(x.astype(jnp.float32), rt["codes"], rt["bitmap"],
                            rt["codebooks"], n_bits, C)
-    y_k = ops.matmul(x, rt, block_m=16, block_n=32, block_k=256)
+    y_k = ops.matmul(x, rt, block_m=16, block_n=128, block_k=256)
     np.testing.assert_allclose(
         np.asarray(y_k), np.asarray(y_ref), rtol=5e-2 if x_dtype == jnp.bfloat16 else 2e-5,
         atol=5e-2 if x_dtype == jnp.bfloat16 else 2e-5,
@@ -106,14 +106,14 @@ def test_dequant_kernel_v2_matches_ref(n_bits, shape):
     R, C = shape
     W = heavy_tailed_weights(R, C, seed=n_bits * 100 + R)
     pk = core.quantize(jnp.asarray(W), n_bits, gamma=0.05)
-    rt = ops.to_runtime(pk, fmt="v2", tile=128)
-    w_k = ops.dequant(rt, block_r=32)
+    rt = ops.to_runtime(pk, fmt="v2", tile=256)
+    w_k = ops.dequant(rt, block_n=128)
     np.testing.assert_array_equal(
         np.asarray(w_k), np.asarray(core.dequantize(pk)))
-    # v2's column block is the checkpoint tile: a block_c request is a
+    # v2's K block is the checkpoint tile: a block_k request is a
     # caller error, not something to silently ignore
     with pytest.raises(TypeError):
-        ops.dequant(rt, block_c=64)
+        ops.dequant(rt, block_k=256)
 
 
 @pytest.mark.parametrize("n_bits", [2, 3, 4])
@@ -126,7 +126,7 @@ def test_matmul_kernel_v2_matches_ref(n_bits, M):
     x = jnp.asarray(
         np.random.default_rng(M).standard_normal((M, C)), jnp.float32)
     y_ref = x.astype(jnp.float32) @ core.dequantize(pk).T
-    y_k = ops.matmul(x, rt, block_m=16, block_n=32)
+    y_k = ops.matmul(x, rt, block_m=16, block_n=128)
     np.testing.assert_allclose(
         np.asarray(y_k), np.asarray(y_ref), rtol=2e-5, atol=2e-5)
 
@@ -201,126 +201,6 @@ def test_runtime_format_bits():
     st_bits = pk.bits_per_weight()["total"]
     assert st_bits < rt_bits < st_bits + 0.85   # bitmap costs ~0.7 extra
     assert rt_bits < 16 / 4                     # still ~4x under bf16
-
-
-def test_matmul_kernel_lowers_for_tpu():
-    """The kernel must *lower* (not execute) for a TPU-like target: build
-    the ClosedJaxpr via abstract eval without interpret mode to catch
-    Python-level BlockSpec errors."""
-    W = heavy_tailed_weights(64, 512, seed=10)
-    pk = core.quantize(jnp.asarray(W), 4, gamma=0.05)
-    rt = ops.to_runtime(pk)
-    x = jnp.zeros((8, 512), jnp.float32)
-    jax.eval_shape(
-        lambda xx, cc, bb, kk: ops.matmul(xx, dict(rt, codes=cc, bitmap=bb,
-                                                   codebooks=kk)),
-        x, rt["codes"], rt["bitmap"], rt["codebooks"],
-    )
-
-
-def test_v2_kernels_lower_for_tpu():
-    """Same Python-level lowering check for the v2 stream-decode kernels
-    (dynamic checkpoint slices, chunked selector compare)."""
-    W = heavy_tailed_weights(64, 512, seed=10)
-    pk = core.quantize(jnp.asarray(W), 4, gamma=0.05)
-    rt = ops.to_runtime(pk, fmt="v2")
-    x = jnp.zeros((8, 512), jnp.float32)
-    jax.eval_shape(
-        lambda xx, cc, ss, oo, dd, kk: ops.matmul(
-            xx, dict(rt, codes=cc, syms=ss, offs=oo, dbase=dd,
-                     codebooks=kk)),
-        x, rt["codes"], rt["syms"], rt["offs"], rt["dbase"],
-        rt["codebooks"],
-    )
-    jax.eval_shape(
-        lambda cc, ss, oo, dd, kk: ops.dequant(
-            dict(rt, codes=cc, syms=ss, offs=oo, dbase=dd, codebooks=kk)),
-        rt["codes"], rt["syms"], rt["offs"], rt["dbase"], rt["codebooks"],
-    )
-
-
-# ---------------------------------------------------------------------------
-# bf16 one-hot codebook-select option (ICQ_ONEHOT_DTYPE)
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("fmt", ["v1", "v2"])
-@pytest.mark.parametrize("n_bits", [2, 3])
-def test_onehot_bf16_parity_tolerance_both_kernels(fmt, n_bits):
-    """onehot='bf16' halves the (BR, BC, C) select temporary; the result
-    is each codebook level rounded to bf16 — matmul and dequant must
-    agree with the f32 one-hot to bf16 mantissa tolerance, and the f32
-    path must stay bitwise-exact against the reference."""
-    R, C = 64, 512
-    W = heavy_tailed_weights(R, C, seed=n_bits * 11)
-    pk = core.quantize(jnp.asarray(W), n_bits, gamma=0.05)
-    rt = ops.to_runtime(pk, fmt=fmt, **(dict(tile=256) if fmt == "v2" else {}))
-
-    kw = dict(block_r=32) if fmt == "v2" else dict(block_r=32, block_c=256)
-    w32 = np.asarray(ops.dequant(rt, onehot="f32", **kw))
-    wbf = np.asarray(ops.dequant(rt, onehot="bf16", **kw))
-    np.testing.assert_array_equal(w32, np.asarray(core.dequantize(pk)))
-    np.testing.assert_allclose(wbf, w32, rtol=8e-3, atol=8e-3)
-    assert not np.array_equal(wbf, w32)   # bf16 rounding is real
-
-    x = jnp.asarray(
-        np.random.default_rng(1).standard_normal((8, C)), jnp.float32)
-    mkw = dict(block_m=8, block_n=32)
-    if fmt == "v1":
-        mkw["block_k"] = 256
-    y32 = np.asarray(ops.matmul(x, rt, onehot="f32", **mkw))
-    ybf = np.asarray(ops.matmul(x, rt, onehot="bf16", **mkw))
-    np.testing.assert_allclose(ybf, y32, rtol=2e-2, atol=2e-2)
-
-
-def test_onehot_env_default_and_vmem_estimate(monkeypatch):
-    from repro.kernels.icq_dequant import onehot_itemsize
-    from repro.kernels.platform import default_onehot_dtype
-
-    monkeypatch.delenv("ICQ_ONEHOT_DTYPE", raising=False)
-    assert default_onehot_dtype() == "f32" and onehot_itemsize() == 4
-    monkeypatch.setenv("ICQ_ONEHOT_DTYPE", "bf16")
-    assert default_onehot_dtype() == "bf16" and onehot_itemsize() == 2
-    monkeypatch.setenv("ICQ_ONEHOT_DTYPE", "fp8")
-    with pytest.raises(ValueError):
-        default_onehot_dtype()
-
-    # the bf16 one-hot halves the dominant VMEM term, so the same block
-    # candidate bills roughly half the budget for large C
-    e32 = backend.vmem_bytes_estimate(128, 128, 512, n_bits=3, C=16,
-                                      onehot="f32")
-    ebf = backend.vmem_bytes_estimate(128, 128, 512, n_bits=3, C=16,
-                                      onehot="bf16")
-    assert ebf < e32
-
-
-def test_onehot_qualifies_autotune_keys_and_rejects_bad_values(monkeypatch):
-    """VMEM admission depends on the one-hot width, so block winners
-    tuned under bf16 must never be replayed by an f32 run (and vice
-    versa): the dtype is part of the cache key. Bad explicit kwargs are
-    a ValueError at the kernel entry, not a KeyError mid-trace."""
-    from repro.kernels import autotune
-
-    monkeypatch.delenv("ICQ_ONEHOT_DTYPE", raising=False)
-    k_f32 = autotune.matmul_key(1, 16, 96, 4, "pallas", True)
-    k_bf16 = autotune.matmul_key(1, 16, 96, 4, "pallas", True,
-                                 onehot="bf16")
-    assert k_f32 != k_bf16 and k_bf16.endswith("_oh-bf16")
-    # the un-suffixed f32 spelling keeps existing cache files valid
-    assert "oh-" not in k_f32
-    # env default flows into un-pinned keys
-    monkeypatch.setenv("ICQ_ONEHOT_DTYPE", "bf16")
-    assert autotune.matmul_key(1, 16, 96, 4, "pallas", True) == k_bf16
-    assert autotune.dequant_key(16, 96, 4, "pallas", True,
-                                fmt="v2").endswith("_v2_oh-bf16")
-    monkeypatch.delenv("ICQ_ONEHOT_DTYPE", raising=False)
-
-    W = heavy_tailed_weights(16, 96, seed=0)
-    pk = core.quantize(jnp.asarray(W), 4, gamma=0.05)
-    rt = ops.to_runtime(pk)
-    with pytest.raises(ValueError, match="onehot"):
-        ops.dequant(rt, onehot="fp8")
-    with pytest.raises(ValueError, match="onehot"):
-        ops.matmul(jnp.zeros((2, 96), jnp.float32), rt, onehot="f16")
 
 
 # ---------------------------------------------------------------------------

@@ -412,10 +412,12 @@ def _attn_leaves(cache):
 
 
 def _logical_view(leaf, pages, bs):
-    """(num_blocks, bs, ...) pool + (B, n_pt) table -> (B, n_pt*bs, ...)."""
+    """Head-major (num_blocks, H, bs, d) pool + (B, n_pt) table ->
+    (B, n_pt*bs, H, d) logical rows."""
     a = np.asarray(leaf)
     pg = np.clip(np.asarray(pages), 0, a.shape[0] - 1)
-    return a[pg].reshape((pg.shape[0], pg.shape[1] * bs) + a.shape[2:])
+    v = np.swapaxes(a[pg], 2, 3)                  # (B, n_pt, bs, H, d)
+    return v.reshape((pg.shape[0], pg.shape[1] * bs) + v.shape[3:])
 
 
 @pytest.mark.parametrize("arch", ["llama3.2-1b", "minicpm3-4b"])
@@ -473,6 +475,7 @@ def test_paged_walk_bitwise_cache_and_logits(arch):
             b = _logical_view(pleaf[lyr], pages, bs)
             for i in range(B):
                 va, vb = a[lyr, i, : plens[i]], b[i, : plens[i]]
+                vb = vb.reshape(va.shape)     # mla: drop the 1-head axis
                 assert np.array_equal(va.view(np.uint8),
                                       vb.view(np.uint8)), (
                     f"{name}: paged lane {i} cache rows diverge bitwise")
@@ -735,7 +738,7 @@ if HAVE_HYPOTHESIS:
         from repro.models.layers import _paged_gather
 
         rng = np.random.default_rng(seed)
-        pool = rng.standard_normal((nb, bs, 2, 3)).astype(np.float32)
+        pool = rng.standard_normal((nb, 2, bs, 3)).astype(np.float32)
         pages = rng.integers(-1, nb, (B, n_pt)).astype(np.int32)
         out = np.asarray(_paged_gather(jnp.asarray(pool),
                                        jnp.asarray(pages)))
@@ -743,7 +746,7 @@ if HAVE_HYPOTHESIS:
         view = out.reshape(B, n_pt, bs, 2, 3)
         for i in range(B):
             for j in range(n_pt):
-                want = pool[max(int(pages[i, j]), 0)]
+                want = pool[max(int(pages[i, j]), 0)].swapaxes(0, 1)
                 assert np.array_equal(view[i, j].view(np.uint8),
                                       want.view(np.uint8))
 

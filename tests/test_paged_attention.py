@@ -39,12 +39,13 @@ def _oracle(q, k_pool, v_pool, pages, kv_len, q2=None, k2_pool=None):
     the straightforward way so the kernel's online-softmax reassociation
     is the only difference."""
     B, Hkv, G, d = q.shape
-    nb, bs = k_pool.shape[0], k_pool.shape[1]
+    nb, bs = k_pool.shape[0], k_pool.shape[2]
     T = pages.shape[1] * bs
     pg = np.clip(pages, 0, nb - 1)
 
-    def gather(pool):
-        return pool[pg].reshape(B, T, Hkv, pool.shape[-1]).astype(np.float64)
+    def gather(pool):                   # head-major (nb, Hkv, bs, d) pool
+        view = np.swapaxes(pool[pg], 2, 3)       # (B, n_pt, bs, Hkv, d)
+        return view.reshape(B, T, Hkv, pool.shape[-1]).astype(np.float64)
 
     s = np.einsum("bhgd,bthd->bhgt", q.astype(np.float64), gather(k_pool))
     if q2 is not None:
@@ -66,12 +67,12 @@ def _case(rng, B, Hkv, G, d, dv, bs, n_pt, nb, kv_len, *, d2=0,
     -1, and ``avoid_block0`` keeps every live page >= 1 so block 0 can
     be scrambled as the clamp-garbage probe."""
     q = rng.standard_normal((B, Hkv, G, d)).astype(np.float32)
-    k_pool = rng.standard_normal((nb, bs, Hkv, d)).astype(np.float32)
-    v_pool = rng.standard_normal((nb, bs, Hkv, dv)).astype(np.float32)
+    k_pool = rng.standard_normal((nb, Hkv, bs, d)).astype(np.float32)
+    v_pool = rng.standard_normal((nb, Hkv, bs, dv)).astype(np.float32)
     q2 = k2_pool = None
     if d2:
         q2 = rng.standard_normal((B, Hkv, G, d2)).astype(np.float32)
-        k2_pool = rng.standard_normal((nb, bs, Hkv, d2)).astype(np.float32)
+        k2_pool = rng.standard_normal((nb, Hkv, bs, d2)).astype(np.float32)
     kv_len = np.asarray(kv_len, np.int32)
     blocks = np.arange(1, nb) if avoid_block0 else np.arange(nb)
     perm = rng.permutation(blocks)
@@ -190,27 +191,8 @@ if HAVE_HYPOTHESIS:
 
 
 # ---------------------------------------------------------------------------
-# TPU lowering + VMEM accounting (no execution)
+# VMEM accounting (the TPU compile of this kernel is in test_tpu_compile.py)
 # ---------------------------------------------------------------------------
-
-def test_paged_attention_lowers_for_tpu():
-    """Build the ClosedJaxpr via abstract eval without interpret mode to
-    catch Python-level BlockSpec/index-map errors (same idiom as the
-    matmul lowering checks)."""
-    rng = np.random.default_rng(0)
-    q, k_pool, v_pool, pages, kv_len, q2, k2_pool = _case(
-        rng, B=2, Hkv=2, G=2, d=8, dv=8, bs=4, n_pt=3, nb=8,
-        kv_len=[9, 4], d2=4)
-    jax.eval_shape(
-        lambda *a: paged_attention(*a[:5], pages_per_step=2,
-                                   interpret=False),
-        q, k_pool, v_pool, pages, kv_len)
-    jax.eval_shape(
-        lambda qq, kk, pg, ln, q2_, k2_: paged_attention(
-            qq, kk, kk, pg, ln, q2=q2_, k2_pool=k2_,
-            pages_per_step=2, interpret=False),
-        q, k_pool, pages, kv_len, q2, k2_pool)
-
 
 def test_vmem_fallback_respects_budget():
     """fallback_pages_per_step picks the largest sweep candidate that
