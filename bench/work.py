@@ -1,0 +1,77 @@
+"""What a launch of the serving step costs at the least: model operations
+and bytes, counted from the shapes, and the chip's least time for them.
+
+A ``Step`` is one launch the window made, as the harness saw it: its
+program (``decode`` or ``fused``) and, for each live lane, the KV rows
+it already held and the tokens it fed. ``Weights`` holds the bytes of the
+prepared quantized matrices as they sit in HBM.
+"""
+from __future__ import annotations
+
+import dataclasses
+import json
+import os
+from typing import Dict, List, Optional, Tuple
+
+from bench.model import Model
+
+PEAKS_FILE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                          "peaks.json")
+
+
+@dataclasses.dataclass
+class Step:
+    kind: str                          # 'decode' | 'fused'
+    lanes: List[Tuple[int, int]]       # (KV rows held before, tokens fed)
+    prompt_tokens: int
+    out_tokens: int
+
+
+@dataclasses.dataclass
+class Weights:
+    layer_bytes: Dict[str, int]        # per layer, by matrix name
+    lm_head_bytes: int
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Peak bf16 FLOP/s and HBM bytes/s of one chip of this kind."""
+    with open(PEAKS_FILE) as f:
+        table = json.load(f)["chips"]
+    if device_kind not in table:
+        raise KeyError(f"no peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(table)}")
+    return table[device_kind]
+
+
+def attn_flops(m: Model, ctx: int, n: int) -> int:
+    """QK^T and PV over every layer for n new tokens after ctx rows."""
+    keys = n * ctx + n * (n + 1) // 2
+    return 4 * m.n_layers * m.n_heads * m.head_dim * keys
+
+
+def step_flops(m: Model, s: Step) -> int:
+    toks = sum(n for _, n in s.lanes)
+    lin = 2 * m.linear_params() * toks
+    head = 2 * m.d_model * m.vocab * len(s.lanes)
+    return lin + head + sum(attn_flops(m, c, n) for c, n in s.lanes)
+
+
+def step_bytes(m: Model, w: Weights, s: Step) -> int:
+    weights = m.n_layers * sum(w.layer_bytes.values()) + w.lm_head_bytes
+    kv = sum((c + n) * m.kv_row_bytes() for c, n in s.lanes)
+    embed = sum(n for _, n in s.lanes) * m.d_model * m.itemsize
+    return weights + kv + embed
+
+
+def least_s(m: Model, w: Weights, s: Step, pk: Dict[str, float]) -> float:
+    return max(step_flops(m, s) / pk["flops"],
+               step_bytes(m, w, s) / pk["hbm_bytes"])
+
+
+def mfu(w) -> Optional[float]:
+    """Per cent of the window the chip would need at its peaks for the
+    window's launches (``least_s`` summed) over the traced window."""
+    if not w.steps_ok or not w.steps or w.peaks is None:
+        return None
+    least = sum(least_s(w.model, w.weights, s, w.peaks) for s in w.steps)
+    return 100.0 * least / w.trace.window_s
