@@ -568,6 +568,9 @@ def main():
           f"{int(s['fused_steps'])} fused / "
           f"{int(s['verify_steps'])} verify / "
           f"{int(s['draft_launches'])} draft)")
+    if s["fused_rows"]:
+        print(f"[serve] fused rows: {int(s['fused_rows_live'])} live of "
+              f"{int(s['fused_rows'])} computed")
     if engine.spec_decode:
         mal = s["mean_accept_len"]
         hist = " ".join(
@@ -589,10 +592,7 @@ def main():
               f"({int(s['kv_blocks'])} x {int(s['kv_block_size'])} rows), "
               f"{int(s['preemptions'])} preemptions, block utilization "
               f"{s['mean_block_utilization']:.2f} mean / "
-              f"{int(s['peak_blocks_in_use'])} peak blocks, "
-              f"decode attn bytes-read est "
-              f"{int(s['attn_live_bytes'])} live / "
-              f"{int(s['attn_logical_bytes'])} logical")
+              f"{int(s['peak_blocks_in_use'])} peak blocks")
     if engine.kv_layout == "paged" and engine.prefix_cache:
         rate = s["prefix_hit_rate"]
         rate_str = f"{rate:.3f}" if rate == rate else "n/a"

@@ -305,7 +305,17 @@ def gqa_apply(
     # NB: pin_bshd here was measured NET-HARMFUL for attention (unlike the
     # SSD mixer): deepseek train compute 21->344 s. See §Perf B5. Attention
     # activations are left to GSPMD propagation.
+    out, new_cache = _gqa_attend(q, k, v, cfg, positions, cache, causal,
+                                 seq_lens)
+    return linear(out.reshape(B, S, -1), p["wo"]), new_cache
 
+
+@jax.named_scope("attn_core")
+def _gqa_attend(q, k, v, cfg, positions, cache, causal, seq_lens):
+    """``gqa_apply``'s KV write and attention, under the program scope
+    ``attn_core`` (the projections around them are ``icq_linear``'s).
+    Returns (out (B, S, n_heads, head_dim), new cache)."""
+    B, S, _, hd = q.shape
     if cache is None:
         out = chunked_attention(
             q, k, v, positions, positions,
@@ -416,7 +426,7 @@ def gqa_apply(
             causal=True, window=cfg.sliding_window, chunk=cfg.attn_chunk,
         )
         new_cache = dict(k=ck, v=cv, index=idx + adv)
-    return linear(out.reshape(B, S, -1), p["wo"]), new_cache
+    return out, new_cache
 
 
 def _check_paged(paged, per_lane: bool):
@@ -544,74 +554,79 @@ def mla_apply(
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_rope, (B, S, H, rd))], axis=-1
         )
-        out = chunked_attention(
-            q, k, vv, positions, positions,
-            causal=True, chunk=cfg.attn_chunk, scale=scale,
-        )
+        with jax.named_scope("attn_core"):
+            out = chunked_attention(
+                q, k, vv, positions, positions,
+                causal=True, chunk=cfg.attn_chunk, scale=scale,
+            )
         return linear(out.reshape(B, S, -1), p["wo"]), None
 
     # decode: absorb W_uk into q, attend directly over the latent cache
-    idx = cache["index"]  # int32 #tokens cached: scalar, or (B,) per-lane
-    pages = cache.get("pages")      # paged latent cache (see gqa_apply)
-    if pages is not None:
-        T = pages.shape[1] * cache["c_kv"].shape[2]
-        cols = _chunk_write_cols(idx, S, T, seq_lens)
-        cc = _paged_scatter(cache["c_kv"], pages, cols, c_kv[:, :, None])
-        cr = _paged_scatter(cache["k_rope"], pages, cols, k_rope)
-        if _paged_attn_arm(S, 0, T) == "pallas":
-            cc_log = cr_log = None      # in-kernel page walk, no gather
+    with jax.named_scope("attn_core"):   # the latent cache write
+        idx = cache["index"]  # #tokens cached: scalar, or (B,) per-lane
+        pages = cache.get("pages")      # paged latent cache (see gqa_apply)
+        if pages is not None:
+            T = pages.shape[1] * cache["c_kv"].shape[2]
+            cols = _chunk_write_cols(idx, S, T, seq_lens)
+            cc = _paged_scatter(cache["c_kv"], pages, cols, c_kv[:, :, None])
+            cr = _paged_scatter(cache["k_rope"], pages, cols, k_rope)
+            if _paged_attn_arm(S, 0, T) == "pallas":
+                cc_log = cr_log = None      # in-kernel page walk, no gather
+            else:
+                cc_log = _paged_gather(cc, pages)[:, :, 0]
+                cr_log = _paged_gather(cr, pages)[:, :, 0]
+        elif idx.ndim:
+            rows = jnp.arange(B, dtype=jnp.int32)[:, None]
+            cols = _chunk_write_cols(idx, S, cache["c_kv"].shape[1], seq_lens)
+            cc = cache["c_kv"].at[rows, cols].set(
+                c_kv.astype(cache["c_kv"].dtype), mode="drop")
+            cr = cache["k_rope"].at[rows, cols].set(
+                k_rope[:, :, 0, :].astype(cache["k_rope"].dtype), mode="drop")
+            cc_log, cr_log = cc, cr
         else:
-            cc_log = _paged_gather(cc, pages)[:, :, 0]
-            cr_log = _paged_gather(cr, pages)[:, :, 0]
-    elif idx.ndim:
-        rows = jnp.arange(B, dtype=jnp.int32)[:, None]
-        cols = _chunk_write_cols(idx, S, cache["c_kv"].shape[1], seq_lens)
-        cc = cache["c_kv"].at[rows, cols].set(
-            c_kv.astype(cache["c_kv"].dtype), mode="drop")
-        cr = cache["k_rope"].at[rows, cols].set(
-            k_rope[:, :, 0, :].astype(cache["k_rope"].dtype), mode="drop")
-        cc_log, cr_log = cc, cr
-    else:
-        cc = jax.lax.dynamic_update_slice(
-            cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), (0, idx, 0)
-        )
-        cr = jax.lax.dynamic_update_slice(
-            cache["k_rope"], k_rope[:, :, 0, :].astype(cache["k_rope"].dtype),
-            (0, idx, 0),
-        )
-        cc_log, cr_log = cc, cr
+            cc = jax.lax.dynamic_update_slice(
+                cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), (0, idx, 0)
+            )
+            cr = jax.lax.dynamic_update_slice(
+                cache["k_rope"],
+                k_rope[:, :, 0, :].astype(cache["k_rope"].dtype),
+                (0, idx, 0),
+            )
+            cc_log, cr_log = cc, cr
     if pages is None:
         T = cc_log.shape[1]
     adv = S if seq_lens is None else seq_lens       # per-lane tokens added
     w_uk = as_dense(p["w_uk"]).reshape(r, H, nd)
     q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, w_uk)           # absorbed q
-    if pages is not None and cc_log is None:
-        # Pallas paged-attention arm over the latent cache: the c_kv
-        # pool doubles as K (latent half) and V; the rope side-channel
-        # rides the kernel's q2/k2 score pair (Hkv=1, G=H).
-        from repro.kernels.paged_attention import paged_attention
-        bs_ = cc.shape[2]
-        qm = (q_lat[:, 0].astype(jnp.float32) * scale).reshape(B, 1, H, r)
-        q2 = (q_rope[:, 0].astype(jnp.float32) * scale).reshape(B, 1, H, rd)
-        pps = _paged_pages_per_step(
-            G=H, d=r, dv=r, bs=bs_, n_pt=pages.shape[1], d2=rd,
-            itemsize=cc.dtype.itemsize)
-        ctx = paged_attention(
-            qm, cc, cc, pages, idx + adv, q2=q2, k2_pool=cr,
-            pages_per_step=pps,
-        ).reshape(B, 1, H, r).astype(q_lat.dtype)                # (B,1,H,r)
-    else:
-        pos_k = jnp.broadcast_to(
-            jnp.arange(T, dtype=jnp.int32)[None], (B, T))
-        k_valid = pos_k < ((idx + adv)[:, None] if idx.ndim else idx + adv)
-        # treat latent dims + rope dims as one concatenated "head dim"
-        q_cat = jnp.concatenate([q_lat, q_rope], axis=-1)        # (B,S,H,r+rd)
-        k_cat = jnp.concatenate(
-            [cc_log, cr_log], axis=-1)[:, :, None, :]            # (B,T,1,r+rd)
-        ctx = chunked_attention(
-            q_cat, k_cat, cc_log[:, :, None, :], positions, pos_k, k_valid,
-            causal=True, chunk=cfg.attn_chunk, scale=scale,
-        )                                                        # (B,S,H,r)
+    with jax.named_scope("attn_core"):
+        if pages is not None and cc_log is None:
+            # Pallas paged-attention arm over the latent cache: the c_kv
+            # pool doubles as K (latent half) and V; the rope side-channel
+            # rides the kernel's q2/k2 score pair (Hkv=1, G=H).
+            from repro.kernels.paged_attention import paged_attention
+            bs_ = cc.shape[2]
+            qm = (q_lat[:, 0].astype(jnp.float32) * scale).reshape(B, 1, H, r)
+            q2 = (q_rope[:, 0].astype(jnp.float32) * scale
+                  ).reshape(B, 1, H, rd)
+            pps = _paged_pages_per_step(
+                G=H, d=r, dv=r, bs=bs_, n_pt=pages.shape[1], d2=rd,
+                itemsize=cc.dtype.itemsize)
+            ctx = paged_attention(
+                qm, cc, cc, pages, idx + adv, q2=q2, k2_pool=cr,
+                pages_per_step=pps,
+            ).reshape(B, 1, H, r).astype(q_lat.dtype)            # (B,1,H,r)
+        else:
+            pos_k = jnp.broadcast_to(
+                jnp.arange(T, dtype=jnp.int32)[None], (B, T))
+            k_valid = pos_k < ((idx + adv)[:, None] if idx.ndim else idx + adv)
+            # treat latent dims + rope dims as one concatenated "head dim"
+            q_cat = jnp.concatenate([q_lat, q_rope], axis=-1)  # (B,S,H,r+rd)
+            k_cat = jnp.concatenate(
+                [cc_log, cr_log], axis=-1)[:, :, None, :]      # (B,T,1,r+rd)
+            ctx = chunked_attention(
+                q_cat, k_cat, cc_log[:, :, None, :], positions, pos_k, k_valid,
+                causal=True, chunk=cfg.attn_chunk, scale=scale,
+            )                                                    # (B,S,H,r)
     w_uv = as_dense(p["w_uv"]).reshape(r, H, vd)
     out = jnp.einsum("bshr,rhv->bshv", ctx, w_uv)
     new_cache = dict(c_kv=cc, k_rope=cr, index=idx + adv)

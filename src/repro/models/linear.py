@@ -11,6 +11,7 @@ through the kernel-backed execution layer instead of a full in-graph
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.icquant import (
@@ -31,16 +32,18 @@ def linear(x: jnp.ndarray, w) -> jnp.ndarray:
     format: gap-stream decode in-graph), ICQRuntime (serving format:
     decode-free bitmap overlay) or ICQPrepared (kernel execution layer:
     fused Pallas / prepared-XLA dispatch) — all stored per output
-    channel."""
-    if isinstance(w, ICQPrepared):
-        return linear_apply(x, w)
-    if isinstance(w, ICQPacked):
-        w_hat = dequantize(w)            # (d_out, d_in)
-        return x @ w_hat.T.astype(x.dtype)
-    if isinstance(w, ICQRuntime):
-        w_hat = dequantize_runtime(w)
-        return x @ w_hat.T.astype(x.dtype)
-    return x @ w
+    channel. Its operations carry the program scope ``icq_linear``, which
+    a device trace reads to attribute time to the model's linears."""
+    with jax.named_scope("icq_linear"):
+        if isinstance(w, ICQPrepared):
+            return linear_apply(x, w)
+        if isinstance(w, ICQPacked):
+            w_hat = dequantize(w)            # (d_out, d_in)
+            return x @ w_hat.T.astype(x.dtype)
+        if isinstance(w, ICQRuntime):
+            w_hat = dequantize_runtime(w)
+            return x @ w_hat.T.astype(x.dtype)
+        return x @ w
 
 
 def as_dense(w, dtype=None) -> jnp.ndarray:

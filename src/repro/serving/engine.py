@@ -155,6 +155,7 @@ from typing import Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.kernels.backend import forced_backend
 from repro.launch.steps import fork_cache_block, make_cache, \
@@ -182,6 +183,74 @@ class _BadLogits(RuntimeError):
 class _ReplayNeeded(RuntimeError):
     """Both the normal launch and its degraded XLA retry failed: the
     engine must preempt the live lanes and replay them."""
+
+
+def _jit(fn, name: str):
+    """``jax.jit`` under a stable program name: a trace names each
+    execution ``jit_<name>``, so the decode and fused programs, their
+    sampled variants and their degraded XLA twins stay told apart."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
+class _LaunchSpans:
+    """Host spans of the continuous loop, on the profiler's clock.
+
+    One ``engine.iteration`` span per loop iteration, opened once the
+    ``on_iteration`` hook has returned, and under it one span per phase
+    of a launch, in this order: ``engine.admit`` (lifecycle pass,
+    admission, block reservation, preemption), ``engine.inputs`` (host
+    arrays and their copies to the device), ``engine.dispatch`` (the
+    jitted call up to its return), ``engine.wait`` (the blocking reads
+    of the launch's outputs) and ``engine.emit`` (per-lane bookkeeping,
+    ``on_token``, finishing). Entering a phase ends the one before; the
+    phase already open is not entered twice. Every phase carries the
+    launch number; the iteration carries the number and the program of
+    its launch (``decode``, ``fused``, ``chunk`` or ``verify``), as does
+    ``engine.dispatch``, which for a fused launch also carries its token
+    rows (``rows``, batch x chunk) and the rows that hold a token
+    (``live``). With no profiler running a span costs under a
+    microsecond.
+    """
+
+    def __init__(self):
+        self._iter = self._phase = self._name = None
+        self._launch = self._program = None
+
+    def begin(self, launch: int) -> None:
+        """Open an iteration whose next launch is number ``launch``."""
+        self.end()
+        self._iter = TraceAnnotation("engine.iteration")
+        self._iter.__enter__()
+        self._launch = launch
+        self.phase("engine.admit")
+
+    def phase(self, name: str, launch: Optional[int] = None,
+              program: Optional[str] = None, **counts: int) -> None:
+        if self._iter is None or (name == self._name and launch is None
+                                  and program is None):
+            return
+        if self._phase is not None:
+            self._phase.__exit__(None, None, None)
+        if launch is not None:
+            self._launch = launch
+        meta = dict(counts, launch=self._launch)
+        if program is not None:
+            self._program = meta["program"] = program
+        self._name = name
+        self._phase = TraceAnnotation(name, **meta)
+        self._phase.__enter__()
+
+    def end(self) -> None:
+        """Close the open phase and iteration, if any."""
+        if self._phase is not None:
+            self._phase.__exit__(None, None, None)
+        if self._iter is not None:
+            if self._program is not None:
+                self._iter.set_metadata(launch=self._launch,
+                                        program=self._program)
+            self._iter.__exit__(None, None, None)
+        self.__init__()
 
 
 def make_serving_step(cfg, sample: bool = True, check: bool = False):
@@ -213,22 +282,24 @@ def make_serving_step(cfg, sample: bool = True, check: bool = False):
              key, pages=None, reset=None):
         logits, cache = decode(params, cache, tokens, pos, pages=pages,
                                reset=reset)
-        toks = sample_tokens(logits, key, temperature, top_k, top_p,
-                             live=live)
-        if check:
-            bad = live & ~jnp.isfinite(logits).all(axis=-1)
-            return toks, cache, bad
-        return toks, cache
+        with jax.named_scope("sampling"):
+            toks = sample_tokens(logits, key, temperature, top_k, top_p,
+                                 live=live)
+            if check:
+                bad = live & ~jnp.isfinite(logits).all(axis=-1)
+                return toks, cache, bad
+            return toks, cache
 
     def greedy_step(params, cache, tokens, pos, live, pages=None,
                     reset=None):
         logits, cache = decode(params, cache, tokens, pos, pages=pages,
                                reset=reset)
-        toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        if check:
-            bad = live & ~jnp.isfinite(logits).all(axis=-1)
-            return jnp.where(live, toks, 0), cache, bad
-        return jnp.where(live, toks, 0), cache
+        with jax.named_scope("sampling"):
+            toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            if check:
+                bad = live & ~jnp.isfinite(logits).all(axis=-1)
+                return jnp.where(live, toks, 0), cache, bad
+            return jnp.where(live, toks, 0), cache
 
     return step if sample else greedy_step
 
@@ -254,20 +325,22 @@ def make_fused_serving_step(cfg, sample: bool = True, check: bool = False):
     def step(params, cache, tokens, pos, lens, live, temperature, top_k,
              top_p, key, pages=None):
         logits, cache = fused(params, cache, tokens, pos, lens, pages=pages)
-        toks = sample_tokens(logits, key, temperature, top_k, top_p,
-                             live=live)
-        if check:
-            bad = live & ~jnp.isfinite(logits).all(axis=-1)
-            return toks, cache, bad
-        return toks, cache
+        with jax.named_scope("sampling"):
+            toks = sample_tokens(logits, key, temperature, top_k, top_p,
+                                 live=live)
+            if check:
+                bad = live & ~jnp.isfinite(logits).all(axis=-1)
+                return toks, cache, bad
+            return toks, cache
 
     def greedy_step(params, cache, tokens, pos, lens, live, pages=None):
         logits, cache = fused(params, cache, tokens, pos, lens, pages=pages)
-        toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        if check:
-            bad = live & ~jnp.isfinite(logits).all(axis=-1)
-            return jnp.where(live, toks, 0), cache, bad
-        return jnp.where(live, toks, 0), cache
+        with jax.named_scope("sampling"):
+            toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            if check:
+                bad = live & ~jnp.isfinite(logits).all(axis=-1)
+                return jnp.where(live, toks, 0), cache, bad
+            return jnp.where(live, toks, 0), cache
 
     return step if sample else greedy_step
 
@@ -608,20 +681,23 @@ class GenerationEngine:
         # so every fork reuses the same trace); built lazily on first use
         self._fork_block = None
 
-        self._decode = jax.jit(make_decode_step(cfg))       # wave path
+        self._decode = _jit(make_decode_step(cfg), "wave_decode_step")
         # continuous path: checked variants (tokens identical to the
         # unchecked programs; the extra `bad` output is the NaN probe the
         # recovery path keys on)
-        self._step = jax.jit(make_serving_step(cfg, check=True))
-        self._step_greedy = jax.jit(
-            make_serving_step(cfg, sample=False, check=True))
+        self._step = _jit(make_serving_step(cfg, check=True),
+                          "decode_step_sampled")
+        self._step_greedy = _jit(
+            make_serving_step(cfg, sample=False, check=True), "decode_step")
         # degraded twins: the *same* programs, but their (lazy) first
         # trace happens under forced_backend('xla') — every matmul is
         # pinned to the bitwise-exact pure-XLA arm. Distinct jit objects
         # so the two arms never share a compilation cache entry.
-        self._step_xla = jax.jit(make_serving_step(cfg, check=True))
-        self._step_greedy_xla = jax.jit(
-            make_serving_step(cfg, sample=False, check=True))
+        self._step_xla = _jit(make_serving_step(cfg, check=True),
+                              "decode_step_sampled_xla")
+        self._step_greedy_xla = _jit(
+            make_serving_step(cfg, sample=False, check=True),
+            "decode_step_xla")
         # recurrent mixers need the lane-reset mask on every decode launch
         self._needs_reset = cfg.family in ("ssm", "hybrid")
         # chunked prefill's device programs. With fused_step (the default
@@ -639,20 +715,24 @@ class GenerationEngine:
         self._fused = self._fused_greedy = None
         self._fused_xla = self._fused_greedy_xla = None
         if self.fused_step:
-            self._fused = jax.jit(make_fused_serving_step(cfg, check=True))
-            self._fused_greedy = jax.jit(
-                make_fused_serving_step(cfg, sample=False, check=True))
+            self._fused = _jit(make_fused_serving_step(cfg, check=True),
+                               "fused_step_sampled")
+            self._fused_greedy = _jit(
+                make_fused_serving_step(cfg, sample=False, check=True),
+                "fused_step")
             # degraded twins (same pattern as the decode programs above)
-            self._fused_xla = jax.jit(
-                make_fused_serving_step(cfg, check=True))
-            self._fused_greedy_xla = jax.jit(
-                make_fused_serving_step(cfg, sample=False, check=True))
+            self._fused_xla = _jit(
+                make_fused_serving_step(cfg, check=True),
+                "fused_step_sampled_xla")
+            self._fused_greedy_xla = _jit(
+                make_fused_serving_step(cfg, sample=False, check=True),
+                "fused_step_xla")
         # second persistent jitted program: S-token prompt-chunk admission
         self._chunk_step = (
             jax.jit(make_prefill_chunk_step(cfg))
             if chunking and not self.fused_step else None)
         self._chunk_step_xla = (
-            jax.jit(make_prefill_chunk_step(cfg))
+            _jit(make_prefill_chunk_step(cfg), "prefill_chunk_step_xla")
             if self._chunk_step is not None else None)
         if chunking:
             from repro.kernels import autotune
@@ -665,7 +745,6 @@ class GenerationEngine:
         self._pool: Optional[KVBlockPool] = None    # built per run (paged)
         self._pages_dev = None    # device mirror of the pool's page table
         self._pages_ver = -1
-        self._row_bytes: Optional[float] = None  # KV bytes per cache row
         self._folded: Dict[int, int] = {}   # rid -> generated tokens already
         #                                     folded into the prompt (preempt)
         self._key = jax.random.PRNGKey(seed)
@@ -725,7 +804,7 @@ class GenerationEngine:
                     spec_draft, raw_params, cfg, batch_size, max_len,
                     weight_cache=weight_cache, prepare_kw=kw,
                     draft_params=draft_params, seed=seed)
-            self._verify = jax.jit(make_spec_verify(cfg))
+            self._verify = _jit(make_spec_verify(cfg), "verify_step")
             from repro.kernels import autotune
 
             # the verify launch carries M = batch * (spec_k + 1) tokens:
@@ -735,6 +814,7 @@ class GenerationEngine:
         self._draft_mark = 0     # drafter.launches already ledgered
 
         self._launch_no = 0           # global launch counter (decode+chunk)
+        self._spans = _LaunchSpans()  # host spans of the continuous loop
         self._degraded_left = 0       # sticky degraded-mode countdown
         self._cancel_pending: set = set()   # rids awaiting cancellation
         self._replayed: Dict[int, int] = {}  # rid -> replay count (cap 2)
@@ -1227,16 +1307,18 @@ class GenerationEngine:
                        extra, live, fault):
         """One checked decode launch under the recovery policy.
 
-        Returns (toks, cache). A launch that raises (injected or genuine
-        RuntimeError) or whose logits come back non-finite on a live
-        lane is retried **once** on the degraded XLA arm with identical
-        inputs — including the PRNG subkey, so a recovered sampled
-        launch draws the very tokens the failed one would have. Failure
-        of the retry raises ``_ReplayNeeded``; the caller preempts and
-        replays the live lanes. The failed launch's cache output is
-        discarded (jitted steps are functional), so a retry never sees
-        half-written state.
+        Returns (toks, cache), toks read back to the host. A launch that
+        raises (injected or genuine RuntimeError) or whose logits come
+        back non-finite on a live lane is retried **once** on the degraded
+        XLA arm with identical inputs — including the PRNG subkey, so a
+        recovered sampled launch draws the very tokens the failed one
+        would have. Failure of the retry raises ``_ReplayNeeded``; the
+        caller preempts and replays the live lanes. The failed launch's
+        cache output is discarded (jitted steps are functional), so a
+        retry never sees half-written state.
         """
+        spans = self._spans
+        spans.phase("engine.inputs")
         d_live, d_temp, d_topk, d_topp = ctrl
         t_dev, p_dev = jnp.asarray(tokens), jnp.asarray(pos)
 
@@ -1251,9 +1333,12 @@ class GenerationEngine:
                         d_temp, d_topk, d_topp, sub)
             ctx = (forced_backend("xla") if degraded
                    else contextlib.nullcontext())
+            spans.phase("engine.dispatch", program="decode")
             with ctx:
                 toks, cache2, bad = prog(*args, **extra)
-            if bool((np.asarray(bad) & live).any()):
+            spans.phase("engine.wait")
+            bad, toks = np.asarray(bad), np.asarray(toks)
+            if bool((bad & live).any()):
                 raise _BadLogits("non-finite logits on a live lane")
             return toks, cache2
 
@@ -1278,6 +1363,7 @@ class GenerationEngine:
                 out = run(True)   # retry once, bitwise-exact XLA arm
             except RuntimeError:
                 raise _ReplayNeeded("decode launch failed twice")
+        spans.phase("engine.emit")
         if self._degraded_left > 0:
             self._degraded_left -= 1
             self.metrics.on_degraded_step()
@@ -1334,6 +1420,7 @@ class GenerationEngine:
                     lens[i] = min(lens[i], max(0, backed - int(pos[i])))
         if not lens.any():
             return cache, False
+        self._spans.phase("engine.inputs")
         ctoks = np.zeros((B, S), np.int32)
         for i in range(B):
             if lens[i]:
@@ -1351,8 +1438,10 @@ class GenerationEngine:
             prog = self._chunk_step_xla if degraded else self._chunk_step
             ctx = (forced_backend("xla") if degraded
                    else contextlib.nullcontext())
+            pages = self._pages_mirror()
+            self._spans.phase("engine.dispatch", program="chunk")
             with ctx:
-                return prog(*args, pages=self._pages_mirror())
+                return prog(*args, pages=pages)
 
         degraded = self._degraded_left > 0
         try:
@@ -1369,6 +1458,7 @@ class GenerationEngine:
                 cache = run(True)   # retry once, bitwise-exact XLA arm
             except (FaultInjected, RuntimeError):
                 raise _ReplayNeeded("chunk launch failed twice")
+        self._spans.phase("engine.emit")
         if self._degraded_left > 0:
             self._degraded_left -= 1
             self.metrics.on_degraded_step()
@@ -1428,6 +1518,8 @@ class GenerationEngine:
         """
         B = self.batch_size
         sched = self._sched
+        spans = self._spans
+        spans.phase("engine.inputs")
         ctoks = np.zeros((B, self.prefill_chunk), np.int32)
         n_prompt = 0
         for i in range(B):
@@ -1440,6 +1532,7 @@ class GenerationEngine:
             else:                        # decode lane: last emitted token
                 ctoks[i, 0] = tokens[i, 0]
         d_live, d_temp, d_topk, d_topp = ctrl
+        rows, n_live = B * self.prefill_chunk, int(lens.sum())
         # .copy(): argument transfers are async and pos mutates below
         t_dev = jnp.asarray(ctoks)
         p_dev = jnp.asarray(pos.copy())
@@ -1456,9 +1549,14 @@ class GenerationEngine:
                         d_temp, d_topk, d_topp, sub)
             ctx = (forced_backend("xla") if degraded
                    else contextlib.nullcontext())
+            pages = self._pages_mirror()
+            spans.phase("engine.dispatch", program="fused", rows=rows,
+                        live=n_live)
             with ctx:
-                toks, cache2, bad = prog(*args, pages=self._pages_mirror())
-            if bool((np.asarray(bad) & live).any()):
+                toks, cache2, bad = prog(*args, pages=pages)
+            spans.phase("engine.wait")
+            bad, toks = np.asarray(bad), np.asarray(toks)
+            if bool((bad & live).any()):
                 raise _BadLogits("non-finite logits on a live lane")
             return toks, cache2
 
@@ -1482,11 +1580,11 @@ class GenerationEngine:
                 out = run(True)   # retry once, bitwise-exact XLA arm
             except RuntimeError:
                 raise _ReplayNeeded("fused launch failed twice")
+        spans.phase("engine.emit")
         if self._degraded_left > 0:
             self._degraded_left -= 1
             self.metrics.on_degraded_step()
-        toks, cache = out
-        nxt_tok = np.asarray(toks)
+        nxt_tok, cache = out
         t_now = self._now()
         self.metrics.on_step(
             int(live.sum()), sched.queue_depth, t_now, kind="fused",
@@ -1494,7 +1592,7 @@ class GenerationEngine:
                            else self._pool.used_blocks),
             shared_blocks=(self._pool.shared_blocks()
                            if self.prefix_cache else None))
-        self._note_attn_bytes(live, pos + lens)
+        self.metrics.on_fused_rows(rows, n_live)
         if n_prompt:
             self.metrics.on_prompt_tokens(n_prompt, kind="prefill")
 
@@ -1583,6 +1681,8 @@ class GenerationEngine:
         slots = [i for i in range(B) if live[i] and ks[i] > 0]
         if not slots:
             return cache, False, fault, False
+        spans = self._spans
+        spans.phase("engine.inputs")
         d0 = self._drafter.launches
         try:
             drafts = self._drafter.propose(
@@ -1618,10 +1718,14 @@ class GenerationEngine:
             if fault == "raise":
                 raise FaultInjected(
                     f"injected 'raise' at verify launch {self._launch_no - 1}")
+            pages = self._pages_mirror()
+            spans.phase("engine.dispatch", program="verify")
             tgt, cache2, bad = self._verify(
                 self.params, cache, t_dev, p_dev, l_dev, d_live,
-                pages=self._pages_mirror())
-            if bool((np.asarray(bad) & live).any()):
+                pages=pages)
+            spans.phase("engine.wait")
+            bad, tgt = np.asarray(bad), np.asarray(tgt)
+            if bool((bad & live).any()):
                 raise _BadLogits("non-finite logits on a live lane")
             if fault == "nan":
                 raise _BadLogits(
@@ -1639,7 +1743,7 @@ class GenerationEngine:
             # sees the pre-verify cache, bit-for-bit
             return cache, False, None, False
 
-        tgt = np.asarray(tgt)
+        spans.phase("engine.emit")
         t_now = self._now()
         self.metrics.on_step(
             int(live.sum()), sched.queue_depth, t_now, kind="verify",
@@ -1647,7 +1751,6 @@ class GenerationEngine:
                            else self._pool.used_blocks),
             shared_blocks=(self._pool.shared_blocks()
                            if self.prefix_cache else None))
-        self._note_attn_bytes(live, pos + lens)
 
         dirty = False
         for i in range(B):
@@ -1700,25 +1803,6 @@ class GenerationEngine:
                 self._pool.trim(i, new_pos + 1)
         return cache2, True, None, dirty
 
-    def _note_attn_bytes(self, live: np.ndarray,
-                         kv_lens: np.ndarray) -> None:
-        """Accumulate the paged decode-attention bytes-read estimate for
-        one launch. ``kv_lens[i]`` is lane i's KV length after the
-        launch; 'logical' bills the full page-table span for every live
-        lane (what a contiguous gather streams through HBM), 'live'
-        only the blocks actually mapped (what the paged Pallas kernel
-        streams through VMEM). No-op for contiguous caches."""
-        if self._pool is None or self._row_bytes is None:
-            return
-        bs = self.kv_block_size
-        logical = live_rows = 0
-        for i in range(self.batch_size):
-            if live[i]:
-                logical += self._n_pt * bs
-                live_rows += -(-int(kv_lens[i]) // bs) * bs
-        self.metrics.on_attn_bytes(int(logical * self._row_bytes),
-                                   int(live_rows * self._row_bytes))
-
     def _pages_mirror(self):
         """Device mirror of the pool's page table, refreshed only when the
         allocator mutated it (same pattern as the ctrl arrays)."""
@@ -1755,10 +1839,6 @@ class GenerationEngine:
             cache_bytes,
             kv_blocks=self.kv_blocks if paged else None,
             kv_block_size=self.kv_block_size if paged else None)
-        # per-row KV bytes for the attention bytes-read estimate (coarse:
-        # the small index/pages leaves amortize over the pool rows)
-        self._row_bytes = (cache_bytes / (self.kv_blocks * self.kv_block_size)
-                           if paged else None)
         # sliding-window + paged attention: the per-call arm gate
         # (models/layers._paged_attn_arm) routes any decode whose window
         # is shorter than the page-table span down the XLA gather arm —
@@ -1779,8 +1859,10 @@ class GenerationEngine:
         ctrl = None        # device mirror of (live, temp, topk, topp):
         ctrl_dirty = True  # refreshed only on admit/finish, not per step
         greedy_only = True  # no live lane samples; refreshed with ctrl
+        spans = self._spans
 
         while sched.has_work():
+            spans.end()
             if self.on_iteration is not None:
                 # service-layer hook (replica inbox drain / heartbeat /
                 # kill). It may submit, cancel or raise; a raise
@@ -1788,6 +1870,7 @@ class GenerationEngine:
                 self.on_iteration()
                 if not sched.has_work():
                     break
+            spans.begin(self._launch_no)
             now = self._now()
             if self._lifecycle_pass(now, live, pos, tokens):
                 ctrl_dirty = True
@@ -1847,6 +1930,8 @@ class GenerationEngine:
                 # chunking (the decode step teacher-forces mid-bulk lanes
                 # one extra prompt token — order-free per lane, so token
                 # streams are unchanged; only TTFT timing improves).
+                if launched:
+                    spans.phase("engine.admit", launch=self._launch_no)
             if paged:
                 # back every lane's next write position; exhaustion
                 # preempts the youngest lane(s) into the queue.
@@ -1876,6 +1961,10 @@ class GenerationEngine:
                         continue
                 else:
                     fault = "raise"  # contiguous: no allocator to exhaust
+            # per-lane token counts of a fused launch: reserves the blocks
+            # of each lane's prompt chunk, so it belongs to admission
+            lens = self._fused_lens(live, pos) if self.fused_step else None
+            spans.phase("engine.inputs")
             if ctrl_dirty:
                 ctrl = tuple(jnp.asarray(a)
                              for a in (live, temp, topk, topp))
@@ -1909,7 +1998,6 @@ class GenerationEngine:
                     ctrl_dirty |= dirty
                     continue
             if self.fused_step:
-                lens = self._fused_lens(live, pos)
                 if (lens > 1).any():
                     # at least one lane still has bulk prompt: this whole
                     # mixed iteration is ONE fused launch (chunk admission
@@ -1933,7 +2021,7 @@ class GenerationEngine:
             if self._needs_reset:
                 extra["reset"] = jnp.asarray(reset.copy())
             try:
-                toks, cache = self._decode_launch(
+                nxt_tok, cache = self._decode_launch(
                     cache, tokens, pos, ctrl, greedy_only, sub, extra,
                     live, fault)
             except _ReplayNeeded:
@@ -1941,7 +2029,6 @@ class GenerationEngine:
                 ctrl_dirty = True
                 continue
             reset[:] = False    # consumed by this launch
-            nxt_tok = np.asarray(toks)
             t_now = self._now()
             self.metrics.on_step(
                 int(live.sum()), sched.queue_depth, t_now,
@@ -1949,7 +2036,6 @@ class GenerationEngine:
                                else self._pool.used_blocks),
                 shared_blocks=(self._pool.shared_blocks()
                                if self.prefix_cache else None))
-            self._note_attn_bytes(live, pos + 1)
             if window_xla:
                 self.metrics.on_window_fallback()
 
@@ -1981,6 +2067,7 @@ class GenerationEngine:
                     ctrl_dirty = True
             if n_prompt:
                 self.metrics.on_prompt_tokens(n_prompt)
+        spans.end()
         if self.prefix_cache:
             self._cache = cache   # retained chains point into it
             self.metrics.set_session_stats(len(self._sessions))

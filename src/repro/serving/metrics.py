@@ -10,11 +10,10 @@ numbers stay meaningful either way:
     and how many times the request was preempted and requeued;
   * per engine run: aggregate generated tokens/s over the active window,
     mean slot occupancy and queue depth sampled once per step, the
-    prefill-vs-decode token split (plus fused prefill+decode launches
-    and the total launch count), the paged-KV footprint (cache bytes,
-    pool geometry, preemptions, blocks-in-use), the decode-attention
-    bytes-read estimate (logical full-table span vs live mapped
-    blocks), and the
+    prefill-vs-decode token split (plus fused prefill+decode launches,
+    the token rows they computed and the rows that were live, and the
+    total launch count), the paged-KV footprint (cache bytes, pool
+    geometry, preemptions, blocks-in-use), and the
     **fault-tolerance ledger**: timeouts / cancellations / expired /
     sheds / failed terminal counts, injected-or-detected fault count by
     kind, degraded-mode steps (launches retried or pinned to the
@@ -143,6 +142,8 @@ class MetricsCollector:
         self.prefill_steps: int = 0          # chunk-program launches
         self.decode_steps: int = 0           # decode-program launches
         self.fused_steps: int = 0            # fused prefill+decode launches
+        self.fused_rows: int = 0             # rows those launches computed
+        self.fused_rows_live: int = 0        # of which consumed a token
         self.prefill_tokens: int = 0         # prompt tokens via chunk program
         self.prompt_decode_tokens: int = 0   # prompt tokens walked 1/step
         # speculative-decode ledger (serving/spec_decode.py). Tokens/s
@@ -162,12 +163,6 @@ class MetricsCollector:
         # arm gate routed down the XLA gather arm because the window is
         # shorter than the page-table span (docs/ENV.md, ICQ_PAGED_ATTN)
         self.window_fallbacks: int = 0
-        # paged-attention bytes-read estimate, accumulated per launch:
-        # 'logical' bills the full page-table span every lane (what a
-        # contiguous gather streams), 'live' only the blocks actually
-        # mapped to each live lane (what the paged kernel streams)
-        self.attn_logical_bytes: int = 0
-        self.attn_live_bytes: int = 0
         # paged-KV observability (kv_layout='paged')
         self.preemptions: int = 0            # preempt-and-requeue events
         self.blocks_in_use_samples: List[int] = []   # sampled once per step
@@ -240,6 +235,14 @@ class MetricsCollector:
             self.verify_steps += 1
         else:
             self.decode_steps += 1
+
+    def on_fused_rows(self, rows: int, live: int):
+        """One fused launch's token rows: ``rows`` = batch x chunk, what
+        the program computes whatever the lanes hold; ``live`` = the
+        rows that consumed a prompt or decode token (its ``lens.sum()``).
+        Their ratio is the share of the fused step's work that is used."""
+        self.fused_rows += int(rows)
+        self.fused_rows_live += int(live)
 
     def on_preempt(self, rid: int, t: float):
         """Lane preempted (pool exhausted / replay) + request requeued."""
@@ -337,16 +340,6 @@ class MetricsCollector:
         self.kv_blocks = kv_blocks
         self.kv_block_size = kv_block_size
 
-    def on_attn_bytes(self, logical: int, live: int):
-        """One launch's decode-attention KV bytes-read estimate:
-        ``logical`` = full page-table span per live lane (the contiguous
-        gather's streaming cost), ``live`` = only the blocks each lane
-        actually maps (what the paged Pallas kernel streams through
-        VMEM). The gap between the two running totals is the bandwidth
-        the paged kernel saves."""
-        self.attn_logical_bytes += int(logical)
-        self.attn_live_bytes += int(live)
-
     def on_prompt_tokens(self, n: int, kind: str = "decode"):
         """Prompt tokens consumed this step: ``kind='prefill'`` via the
         S-token chunk program, ``'decode'`` teacher-forced 1/step."""
@@ -396,6 +389,8 @@ class MetricsCollector:
             prefill_steps=float(self.prefill_steps),
             decode_steps=float(self.decode_steps),
             fused_steps=float(self.fused_steps),
+            fused_rows=float(self.fused_rows),
+            fused_rows_live=float(self.fused_rows_live),
             verify_steps=float(self.verify_steps),
             draft_launches=float(self.draft_launches),
             launches=float(self.prefill_steps + self.decode_steps
@@ -413,8 +408,6 @@ class MetricsCollector:
             paged_attn_window_fallbacks=float(self.window_fallbacks),
             prefill_tokens=float(self.prefill_tokens),
             prompt_decode_tokens=float(self.prompt_decode_tokens),
-            attn_logical_bytes=float(self.attn_logical_bytes),
-            attn_live_bytes=float(self.attn_live_bytes),
             preemptions=float(self.preemptions),
             cache_bytes=(float(self.cache_bytes)
                          if self.cache_bytes is not None else float("nan")),
