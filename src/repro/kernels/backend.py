@@ -356,9 +356,9 @@ def vmem_bytes_estimate(block_m: int, block_n: int, block_k: int, *,
     """Rough VMEM bytes for one fused-matmul grid step (dequant is a
     subset): double-buffered input and output blocks, the accumulator,
     and about six (BK, BN) int32/f32 temporaries of the tile rebuild
-    (unpacked codes, selector, select tree). v2 adds its symbol block,
-    the per-symbol decode temporaries and the rel scratch (``s_cols``
-    unpacked symbols per row). Used to reject or clamp block candidates
+    (unpacked codes, selector, select tree). v2 adds its symbol block
+    and the per-symbol decode temporaries (``s_cols`` unpacked symbols
+    per row). Used to reject or clamp block candidates
     before the compiler does, not to bill exact bytes."""
     f32 = 4
     if accum is None:
@@ -370,7 +370,7 @@ def vmem_bytes_estimate(block_m: int, block_n: int, block_k: int, *,
     est += 2 * C * block_n * f32                       # codebooks
     est += 6 * block_k * block_n * f32                 # tile temporaries
     if fmt == "v2":
-        est += 6 * s_cols * block_n * 4               # syms, decode, scratch
+        est += 6 * s_cols * block_n * 4               # syms, decode
     else:
         est += 2 * block_k // 32 * block_n * 4         # bitmap plane
     return est

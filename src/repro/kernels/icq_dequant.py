@@ -7,8 +7,9 @@ runs along d_in. A kernel therefore rebuilds a transposed weight tile
 
   * codes: n bit planes, unpacked with shifts and masks, 8 sublanes at a
     time (no minor-dim reshape);
-  * selector: v1 reads a 1-bit plane in the same layout; v2 decodes its
-    tile of the checkpointed gap stream (``_tile_selector``);
+  * selector: a 1-bit plane in the same layout, which v1 reads and v2
+    builds from its tile of the checkpointed gap stream
+    (``_tile_selector``);
   * codebook: a binary tree of selects over the 2^(n+1) per-row levels
     on the VPU — each weight is one of its row's stored levels, exactly.
 
@@ -24,7 +25,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import PLANE_ROWS
 
@@ -96,7 +96,7 @@ def _exclusive_prefix_rows(x: jnp.ndarray) -> jnp.ndarray:
 
 
 def _tile_selector(syms: jnp.ndarray, offs: jnp.ndarray, dbase: jnp.ndarray,
-                   kk, rel_ref, *, b: int, block_k: int) -> jnp.ndarray:
+                   kk, *, b: int, block_k: int) -> jnp.ndarray:
     """Checkpointed gap stream -> (BK, BN) 0/1 selector of K tile kk.
 
     syms:  (SW, BN) uint32 — each row's packed b-bit gap symbols down
@@ -106,17 +106,16 @@ def _tile_selector(syms: jnp.ndarray, offs: jnp.ndarray, dbase: jnp.ndarray,
            the row's symbol count).
     dbase: (T, BN) — kk*block_k - dbase[kk] is the position consumed
            before the tile's first symbol.
-    rel_ref: (k_b * round_up(SW, 8), BN) int32 VMEM scratch.
 
     Only the tile's symbols [offs[kk], offs[kk+1]) contribute: their
     increments (escape = 2^b - 1 positions, no emission) are prefix
-    summed exactly on top of the checkpoint base, and each emitted
-    position sets its selector row. No dense bitmap ever exists.
+    summed exactly on top of the checkpoint base. Each emitted position
+    sets one bit of a (BK/32, BN) 1-bit plane in ``_unpack_planes``'
+    layout, which is unpacked once; no dense bitmap reaches HBM.
     """
     k_b = 32 // b
     m = (1 << b) - 1
     SW, BN = syms.shape
-    SWp = rel_ref.shape[0] // k_b
     o0, o1 = _row_at(offs, kk), _row_at(offs, kk + 1)         # (1, BN)
     d0 = _row_at(dbase, kk)
     w = _as_i32(syms)
@@ -131,25 +130,26 @@ def _tile_selector(syms: jnp.ndarray, offs: jnp.ndarray, dbase: jnp.ndarray,
     for inc in incs:
         pre.append(inc if not pre else pre[-1] + inc)
     base = _exclusive_prefix_rows(pre[-1]) - d0 - 1
+
+    # Tile row k is bit (k // 8) % 32 of plane word (k // 256) * 8 + k % 8.
+    # A miss (-1) or a position outside [0, BK) maps to a word outside
+    # [0, BK/32), so it sets nothing.
+    word, bit = [], []
     for j in range(k_b):
-        rel_ref[j * SWp:j * SWp + SW, :] = jnp.where(
-            emits[j], base + pre[j], -1)                      # -1: no hit
-
-    rows = jax.lax.broadcasted_iota(jnp.int32, (block_k, BN), 0)
-
-    def body(i, sel):
-        for j in range(k_b):
-            rel = rel_ref[pl.ds(j * SWp + i, 1), :]           # (1, BN)
-            sel = jnp.where(rel == rows, 1, sel)
-        return sel
-
-    return jax.lax.fori_loop(0, SW, body,
-                             jnp.zeros((block_k, BN), jnp.int32))
-
-
-def rel_scratch(SW: int, b: int, block_n: int):
-    """VMEM scratch of ``_tile_selector``."""
-    return pltpu.VMEM(((32 // b) * _round_up(SW, 8), block_n), jnp.int32)
+        rel = jnp.where(emits[j], base + pre[j], -1)          # -1: no hit
+        word.append(((rel >> 8) << 3) | (rel & 7))
+        bit.append(jnp.left_shift(jnp.int32(1), (rel >> 3) & 31))
+    n_words = block_k // 32
+    plane_rows = jax.lax.broadcasted_iota(jnp.int32, (n_words, BN), 0)
+    plane = jnp.zeros((n_words, BN), jnp.int32)
+    for v in range(n_words):
+        # a row's emitted positions are distinct: the sum is the OR
+        hits = jnp.where(word[0] == v, bit[0], 0)
+        for j in range(1, k_b):
+            hits = hits + jnp.where(word[j] == v, bit[j], 0)
+        plane = plane | jnp.where(
+            plane_rows == v, jnp.sum(hits, axis=0, keepdims=True), 0)
+    return _unpack_planes(plane, 1)
 
 
 def _dequant_kernel(codes_ref, bitmap_ref, cb_ref, out_ref, *, n_bits: int):
@@ -159,10 +159,10 @@ def _dequant_kernel(codes_ref, bitmap_ref, cb_ref, out_ref, *, n_bits: int):
 
 
 def _dequant_kernel_v2(codes_ref, syms_ref, offs_ref, dbase_ref, cb_ref,
-                       out_ref, rel_ref, *, n_bits: int, b: int):
+                       out_ref, *, n_bits: int, b: int):
     BK = out_ref.shape[0]
     sel = _tile_selector(syms_ref[...], offs_ref[...], dbase_ref[...],
-                         pl.program_id(2), rel_ref, b=b, block_k=BK)
+                         pl.program_id(2), b=b, block_k=BK)
     idx = (sel << n_bits) + _unpack_planes(codes_ref[...], n_bits)
     out_ref[...] = _codebook_select(idx, cb_ref[...])
 
@@ -244,7 +244,6 @@ def dequant_padded_v2(
         out_specs=pl.BlockSpec((None, block_k, block_n),
                                lambda l, j, kk: (l, kk, j)),
         out_shape=jax.ShapeDtypeStruct((L, pk, pn), jnp.float32),
-        scratch_shapes=[rel_scratch(SW, b, block_n)],
         interpret=interpret,
         name="icq_dequant_v2",
     )(codes, syms, offs, dbase, codebooks)

@@ -23,7 +23,6 @@ from repro.kernels.icq_dequant import (
     _codebook_select,
     _tile_selector,
     _unpack_planes,
-    rel_scratch,
 )
 
 
@@ -64,11 +63,11 @@ def _matmul_kernel(x_ref, codes_ref, bitmap_ref, cb_ref, out_ref, acc_ref,
 
 
 def _matmul_kernel_v2(x_ref, codes_ref, syms_ref, offs_ref, dbase_ref,
-                      cb_ref, out_ref, acc_ref, rel_ref, *, n_bits: int,
-                      b: int, n_k: int):
+                      cb_ref, out_ref, acc_ref, *, n_bits: int, b: int,
+                      n_k: int):
     BK = x_ref.shape[-1]
     sel = _tile_selector(syms_ref[...], offs_ref[...], dbase_ref[...],
-                         pl.program_id(2), rel_ref, b=b, block_k=BK)
+                         pl.program_id(2), b=b, block_k=BK)
     idx = (sel << n_bits) + _unpack_planes(codes_ref[...], n_bits)
     _accumulate(x_ref, _codebook_select(idx, cb_ref[...]), out_ref, acc_ref,
                 n_k)
@@ -167,8 +166,7 @@ def matmul_padded_v2(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((pm, pn), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n),
-                                   accum_scratch_dtype(accum)),
-                        rel_scratch(SW, b, block_n)],
+                                   accum_scratch_dtype(accum))],
         interpret=interpret,
         name="icq_matmul_v2",
     )(x, codes, syms, offs, dbase, codebooks)

@@ -1,4 +1,6 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode."""
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -190,6 +192,50 @@ def test_dispatch_parity_v2_ragged(n_bits, M):
     np.testing.assert_allclose(
         np.asarray(backend.linear_apply(x, prep)), y_ref,
         rtol=2e-5, atol=2e-5)
+
+
+_DEEP_ROWS, _DEEP_D_IN, _DEEP_TILE = 44, 4096, 512    # ragged rows, T = 8
+
+
+@functools.lru_cache(maxsize=None)
+def _deep_stream_runtime(b: int):
+    """A 2-bit v2 runtime dict over a (44, 4096) weight at forced b, with
+    two hand-built rows: row 0 has outliers at 0, 511, 512 and 4095
+    (both edges of a tile, the last position); row 1 holds every outlier
+    in the first three tiles, so tiles 3-7 have none."""
+    W = heavy_tailed_weights(_DEEP_ROWS, _DEEP_D_IN, seed=b)
+    big = 1.5 * float(np.abs(W).max())          # above every other |w|
+    W[0, [0, 511, 512, 4095]] = [big, -big, big, -big]
+    p = core.num_outliers(_DEEP_D_IN, 0.05)
+    early = np.random.default_rng(b).choice(3 * _DEEP_TILE, p, replace=False)
+    W[1, early] = big
+    pk = core.quantize(jnp.asarray(W), 2, gamma=0.05, b=b)
+    rt = ops.to_runtime(pk, fmt="v2", tile=_DEEP_TILE)
+    return pk, rt
+
+
+@pytest.mark.parametrize("arm", ["dequant", "matmul"])
+@pytest.mark.parametrize("b", [2, 3, 6, 11])     # 16 / 10 / 5 / 2 a word
+def test_v2_selector_plane_deep_stream(b, arm):
+    """The v2 selector plane over 8 checkpoint tiles: small b forces
+    escapes, late tiles read symbols deep in the stream, and the rows
+    past 44 (padding up to 128 lanes) have empty streams. The dequant
+    arm equals the core reconstruction bit for bit."""
+    pk, rt = _deep_stream_runtime(b)
+    assert pk.b == b and rt["offs"].shape == (_DEEP_ROWS, 9)
+    mask = np.asarray(core.decode_to_dense_mask(pk.stream))
+    assert mask[0, [0, 511, 512, 4095]].all()
+    assert not mask[1, 3 * _DEEP_TILE:].any()
+    w_ref = np.asarray(core.dequantize(pk))
+    if arm == "dequant":
+        np.testing.assert_array_equal(
+            np.asarray(ops.dequant(rt, block_n=128)), w_ref)
+    else:
+        x = jnp.asarray(np.random.default_rng(b).standard_normal(
+            (8, _DEEP_D_IN)), jnp.float32)
+        np.testing.assert_allclose(
+            np.asarray(ops.matmul(x, rt, block_m=8, block_n=128)),
+            np.asarray(x @ w_ref.T), rtol=2e-5, atol=2e-5)
 
 
 def test_runtime_format_bits():

@@ -4,8 +4,9 @@ Nothing runs: the installed TPU compiler lowers and compiles each kernel
 for a v5e chip that is described, not attached, so a block layout or a
 kernel body the chip's compiler would refuse fails here at no chip time.
 Interpret-mode tests cannot see either. Widths are InternLM2-1.8B's
-(d_model 2048, d_ff 8192, 8 KV heads of 128 with 2 query heads each)
-and MiniCPM3-4B's latent attention (40 heads over a 256-wide latent with
+(d_model 2048, d_ff 8192, 8 KV heads of 128 with 2 query heads each),
+Phi-3-mini's ICQ projections (d_model 3072, d_ff 8192) and MiniCPM3-4B's
+latent attention (40 heads over a 256-wide latent with
 a 32-wide rope side channel). The ICQ weights are real ``prepare()``
 outputs of seeded synthetic weights, so the compiled shapes are the ones
 the serving path builds.
@@ -26,6 +27,8 @@ from repro.kernels.paged_attention import paged_attention
 
 # InternLM2-1.8B projections as (d_out, d_in)
 WIDTHS = [(2048, 2048), (8192, 2048), (2048, 8192)]
+# Phi-3-mini projections: 6 and 16 checkpoint tiles of 512 a row
+PHI3_WIDTHS = [(3072, 3072), (8192, 3072), (3072, 8192)]
 DECODE_M = 8
 
 
@@ -74,9 +77,10 @@ def _compile_arms(prep, sharding):
         p_, prep.block_n, prep.block_k), p)
 
 
-@pytest.mark.parametrize("d_out,d_in", WIDTHS)
+@pytest.mark.parametrize("d_out,d_in", WIDTHS + PHI3_WIDTHS)
 def test_icq_v2_kernels_compile_for_v5e(one_chip, d_out, d_in):
-    """Fused matmul v2 and dequant v2 at 3 bits, InternLM2 widths."""
+    """Fused matmul v2 and dequant v2 at 3 bits, InternLM2 and Phi-3
+    widths."""
     _compile_arms(_prepared(d_out, d_in, 3, "v2"), one_chip)
 
 
