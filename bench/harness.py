@@ -251,19 +251,8 @@ def _pct(xs: List[float], q: float) -> float:
 
 
 def _weight_bytes(m: model_mod.Model, params) -> work.Weights:
-    import jax
-
-    def nbytes(leaf) -> int:
-        total = sum(int(a.nbytes) for a in jax.tree.leaves(leaf))
-        memo = getattr(leaf, "sel_memo", None)
-        return total - (int(memo.nbytes) if memo is not None else 0)
-
-    st = params["stack"]
-    layer = {n: nbytes(st["attn"][n]) // m.n_layers
-             for n in weights.ATTN}
-    layer.update({n: nbytes(st["mlp"][n]) // m.n_layers
-                  for n in weights.MLP})
-    return work.Weights(layer, nbytes(params["lm_head"]))
+    return work.Weights(m.arch.layer_bytes(m, params),
+                        weights.prepared_bytes(params["lm_head"]))
 
 
 def run(config_file: str, traffic_file: str, e2e: Dict[str, str],
@@ -291,7 +280,7 @@ def run(config_file: str, traffic_file: str, e2e: Dict[str, str],
     from repro.serving import GenerationEngine
     from repro.serving.sampling import GREEDY
 
-    m = model_mod.load(config_file)
+    m = model_mod.load(config_file, root)
     spec = traffic.load(traffic_file)
     params, built = weights.load_or_build(m, config_file, cache_dir, log,
                                           root)
@@ -302,7 +291,7 @@ def run(config_file: str, traffic_file: str, e2e: Dict[str, str],
     wb = _weight_bytes(m, params)
     B, L = spec.batch, spec.max_len
     eng = GenerationEngine(
-        params, model_mod.program_config(m), batch_size=B, max_len=L,
+        params, m.arch.program_config(m), batch_size=B, max_len=L,
         weight_cache="prepared", runtime_fmt=m.fmt, mode="continuous",
         sampling=GREEDY, seed=0, prefill_chunk=spec.prefill_chunk,
         kv_layout="paged", kv_block_size=spec.kv_block_size,

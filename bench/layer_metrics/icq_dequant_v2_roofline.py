@@ -19,14 +19,9 @@ def call_work(d_in, d_out, w_bytes):
 
 
 def calls(w, step):
-    """(K, N, weight bytes) of the dequant-arm calls of one launch: every
-    layer matrix of a fused launch (M = batch x chunk); none in a decode
-    launch."""
-    if step.kind != "fused":
-        return []
-    m = w.model
-    per = [(a, b, w.weights.layer_bytes[n]) for n, a, b in m.layer_linears()]
-    return per * m.n_layers
+    """(K, N, weight bytes) of the dequant-arm calls of one launch, as
+    the architecture's module lists them."""
+    return w.model.arch.dequant_calls(w.model, w.weights, step)
 
 
 def read(w):

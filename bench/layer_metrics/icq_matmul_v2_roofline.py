@@ -17,17 +17,9 @@ def call_work(M, d_in, d_out, w_bytes, act_bytes):
 
 
 def calls(w, step):
-    """(M, K, N, weight bytes) of the fused-arm calls of one launch: a
-    decode launch takes every layer matrix and the LM head at M = batch;
-    a fused launch only the LM head (one logits row per lane), its layer
-    matrices going through the dequant arm."""
-    m = w.model
-    head = [(w.batch, m.d_model, m.vocab, w.weights.lm_head_bytes)]
-    if step.kind == "fused":
-        return head
-    per = [(w.batch, a, b, w.weights.layer_bytes[n])
-           for n, a, b in m.layer_linears()]
-    return per * m.n_layers + head
+    """(M, K, N, weight bytes) of the fused-arm calls of one launch, as
+    the architecture's module lists them."""
+    return w.model.arch.matmul_calls(w.model, w.weights, step, w.batch)
 
 
 def read(w):

@@ -2,37 +2,28 @@
 (kernels layer): the chip's least time for the calls the window made
 over the summed device time of the trace's ``paged_attention`` events.
 
-A call is one layer's attention of one decode launch, counted from the
-logical operation: for each live lane, QK^T and PV over its live KV rows
-(4 x heads x head_dim x rows operations) and those rows of K and V read
-once, plus the float32 queries in and outputs out.
+Each call is counted from the logical operation by the architecture's
+module (``paged_attention_calls``): its FLOPs and the bytes it must move
+(the live KV rows read once, the queries in and the outputs out).
 """
 from bench import tracing
 
 KERNEL = "paged_attention"
 
 
-def call_work(m, kv_lens, batch):
-    """(FLOPs, bytes) of one call over lanes with these KV lengths."""
-    rows = sum(kv_lens)
-    flops = 4 * m.n_heads * m.head_dim * rows
-    nbytes = 2 * rows * m.kv_dim * m.itemsize
-    nbytes += 2 * batch * m.n_heads * m.head_dim * 4
-    return flops, nbytes
+def calls(w, step):
+    """(FLOPs, bytes) of each paged-attention call of one launch."""
+    return w.model.arch.paged_attention_calls(w.model, step, w.batch)
 
 
 def read(w):
     if w.trace is None or w.peaks is None or not w.steps_ok:
         return None
     n, secs = tracing.kernel_s(w.trace, KERNEL)
-    dec = [s for s in w.steps if s.kind == "decode"]
-    m = w.model
-    if n == 0 or n != len(dec) * m.n_layers or secs <= 0:
+    cs = [c for s in w.steps for c in calls(w, s)]
+    if n == 0 or n != len(cs) or secs <= 0:
         return None
-    flops = nbytes = 0
-    for s in dec:
-        f, by = call_work(m, [c + k for c, k in s.lanes], w.batch)
-        flops += f * m.n_layers
-        nbytes += by * m.n_layers
+    flops = sum(f for f, _ in cs)
+    nbytes = sum(b for _, b in cs)
     least = max(flops / w.peaks["flops"], nbytes / w.peaks["hbm_bytes"])
     return 100.0 * least / secs

@@ -1,19 +1,28 @@
 """``BENCHMARK.json``: load it, check it against the benchmark's contract,
 and find the files that belong to a cell by name.
 
-A cell ``<config>.<traffic>`` is served from three kinds of files, each
-found by name so that a new configuration, traffic mix or per-layer
-metric is added by adding a file:
+A cell ``<config>.<traffic>`` is served from four kinds of files, each
+found by name so that a new configuration, architecture, traffic mix or
+per-layer metric is added by adding a file:
 
   bench/configs/<config>.json        model sizes, quantization, check limit
+  bench/archs/<module>.py            one module per architecture, found by
+                                     the name the configuration publishes
+                                     (``published.architectures[0]``)
+                                     among those the module lists in its
+                                     ``ARCHITECTURES``
   bench/traffic/<traffic>.json       loop, lanes, lengths, chunking
   bench/layer_metrics/<metric>.py    one reader per per-layer metric
 """
 from __future__ import annotations
 
+import glob
+import hashlib
+import importlib.util
 import json
 import os
 import re
+import sys
 from typing import Dict, List
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
@@ -120,6 +129,11 @@ def validate(m: dict, root: str = ROOT) -> None:
         files.add(f)
         if not os.path.isfile(os.path.join(root, f)):
             raise ManifestError(f"config file missing: {f!r}")
+        with open(os.path.join(root, f)) as fh:
+            try:
+                architecture(architecture_name(json.load(fh), f), root)
+            except json.JSONDecodeError as e:
+                raise ManifestError(f"config file {f!r}: {e}") from None
         red = c["reduced"]
         if not isinstance(red, list) or len(red) > 16:
             raise ManifestError("reduced: a list of at most 16 keys")
@@ -220,6 +234,49 @@ def config_path(name: str, m: dict, root: str = ROOT) -> str:
         if c["name"] == name:
             return os.path.join(root, c["file"])
     raise KeyError(name)
+
+
+def architecture_name(config: dict, where: str) -> str:
+    """The architecture a configuration file publishes."""
+    names = config.get("published", {}).get("architectures")
+    if not isinstance(names, list) or not names or not isinstance(
+            names[0], str):
+        raise ManifestError(f"{where}: published.architectures must name "
+                            f"the architecture")
+    return names[0]
+
+
+def _load_arch(path: str):
+    """The module in ``path``, loaded once per process."""
+    path = os.path.abspath(path)
+    name = ("bench_arch_" + re.sub(r"\W", "_", os.path.basename(path)[:-3])
+            + "_" + hashlib.sha1(path.encode()).hexdigest()[:8])
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]
+
+
+def architecture(name: str, root: str = ROOT):
+    """The one module of ``<root>/bench/archs`` whose ``ARCHITECTURES``
+    lists ``name``. None or two is a ManifestError that names it."""
+    files = sorted(glob.glob(os.path.join(root, "bench", "archs", "*.py")))
+    mods = [_load_arch(f) for f in files
+            if not os.path.basename(f).startswith("_")]
+    found = [m for m in mods if name in getattr(m, "ARCHITECTURES", ())]
+    if len(found) != 1:
+        raise ManifestError(
+            f"architecture {name!r}: claimed by "
+            f"{[os.path.basename(m.__file__) for m in found]} in "
+            f"bench/archs, which must hold exactly one module that lists "
+            f"it in ARCHITECTURES")
+    return found[0]
 
 
 def traffic_path(name: str, root: str = ROOT) -> str:

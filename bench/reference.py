@@ -5,10 +5,10 @@ weights from the configuration's seed (``bench/weights.py``), quantizes
 each matrix with its own ICQuant RTN (the paper's per-output-channel
 partition: the top ``gamma`` share of |w| in each row are outliers; the
 inliers take a uniform n-bit grid over their range, the outliers a signed
-split: n-1 bits on each tail), and runs the dense GQA decoder
-(RMSNorm, rotary embedding in the rotate-half layout, causal softmax
-attention with shared KV heads, SwiGLU MLP) one layer at a time over whole
-sequences: no kernels, no cache, no batching. Every matrix product runs at
+split: n-1 bits on each tail), and runs the model one layer at a time over
+whole sequences: the embedding, each layer as the architecture's module
+(``bench/archs/``) computes it, then the final norm and the LM head. No
+kernels, no cache, no batching. Every matrix product runs at
 ``Precision.HIGHEST``; the unquantized leaves (embedding, norm gains) are
 rounded to the configuration's dtype first, since that is the model the
 program serves.
@@ -118,47 +118,6 @@ def _rms(x, g, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * g
 
 
-def _rope(x, theta):
-    """x (T, H, D); rotate-half rotary embedding at positions 0..T-1."""
-    T, _, D = x.shape
-    inv = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
-    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None]
-    cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
-    x1, x2 = x[..., : D // 2], x[..., D // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-@functools.partial(jax.jit, static_argnums=(3, 4))
-def layer(x, w, g, m: Model, lower: bool):
-    """One decoder layer over a whole sequence x (T, d)."""
-    T = x.shape[0]
-    H, Hk, D = m.n_heads, m.n_kv_heads, m.head_dim
-    h = _rms(x, g[0], m.norm_eps)
-    q = _rope(_mm(h, w["wq"], lower).reshape(T, H, D), m.rope_theta)
-    k = _rope(_mm(h, w["wk"], lower).reshape(T, Hk, D), m.rope_theta)
-    v = _mm(h, w["wv"], lower).reshape(T, Hk, D)
-    rep = H // Hk
-    k = jnp.repeat(k, rep, axis=1)
-    v = jnp.repeat(v, rep, axis=1)
-    if lower:
-        q, k, v = _lower(q), _lower(k), _lower(v)
-    s = jnp.einsum("thd,shd->hts", q, k, precision=HI) / math.sqrt(D)
-    i = jnp.arange(T)
-    mask = i[None, :] <= i[:, None]
-    if m.sliding_window:
-        mask &= i[None, :] > i[:, None] - m.sliding_window
-    s = jnp.where(mask[None], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    if lower:
-        p = _lower(p)
-    o = jnp.einsum("hts,shd->thd", p, v, precision=HI).reshape(T, H * D)
-    x = x + _mm(o, w["wo"], lower)
-    h = _rms(x, g[1], m.norm_eps)
-    a = _mm(h, w["w_gate"], lower)
-    b = _mm(h, w["w_up"], lower)
-    return x + _mm(jax.nn.silu(a) * b, w["w_down"], lower)
-
-
 @functools.partial(jax.jit, static_argnums=(4, 5, 6))
 def head(x, start, g, w, m: Model, rows: int, lower: bool):
     """Logits of ``rows`` positions of x (T, d) from ``start`` on."""
@@ -167,13 +126,13 @@ def head(x, start, g, w, m: Model, rows: int, lower: bool):
     return _mm(_rms(x, g, m.norm_eps), w, lower)
 
 
-def _served(m: Model, name: str, layer_i: int = 0):
+def served(m: Model, name: str, layer_i: int = 0):
     """An unquantized leaf as served: rounded to the model's dtype."""
     a = weights.leaf(m, name, layer_i)
     return a.astype(jnp.dtype(m.dtype)).astype(jnp.float32)
 
 
-def _quantized(m: Model, name: str, layer_i: int = 0):
+def quantized(m: Model, name: str, layer_i: int = 0):
     return icq_rtn(weights.leaf(m, name, layer_i), m.bits, m.gamma)
 
 
@@ -189,7 +148,7 @@ def logits(m: Model, seqs: Sequence[Tuple[np.ndarray, int]], pad_to: int,
     times = {} if times is None else times
     for k in ("quantize", "layers"):
         times.setdefault(k, 0.0)
-    embed = _served(m, "embed")
+    embed = served(m, "embed")
     xs = []
     for toks, _ in seqs:
         ids = np.zeros((pad_to,), np.int32)
@@ -198,17 +157,16 @@ def logits(m: Model, seqs: Sequence[Tuple[np.ndarray, int]], pad_to: int,
     del embed
     for li in range(m.n_layers):
         t = time.perf_counter()
-        w = {n: _quantized(m, n, li) for n, _, _ in m.layer_linears()}
-        g = jnp.stack([_served(m, "ln1", li), _served(m, "ln2", li)])
-        jax.block_until_ready(w)
+        w = jax.block_until_ready(m.arch.layer_weights(m, li))
         times["quantize"] += time.perf_counter() - t
         t = time.perf_counter()
-        xs = jax.block_until_ready([layer(x, w, g, m, lower) for x in xs])
+        xs = jax.block_until_ready([m.arch.layer(x, w, m, lower)
+                                    for x in xs])
         times["layers"] += time.perf_counter() - t
         del w
     t = time.perf_counter()
-    g = _served(m, "final_norm")
-    w = jax.block_until_ready(_quantized(m, "lm_head"))
+    g = served(m, "final_norm")
+    w = jax.block_until_ready(quantized(m, "lm_head"))
     times["quantize"] += time.perf_counter() - t
     out = []
     for x, (toks, n_prompt) in zip(xs, seqs):

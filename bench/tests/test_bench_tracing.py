@@ -79,7 +79,7 @@ def test_json_round_trip(tmp_path):
 
 
 def _window(t, steps, m):
-    layer = {n: a * b // 4 for n, a, b in m.layer_linears()}
+    layer = {n: a * b // 4 for n, a, b in m.arch.layer_linears(m)}
     return Window(model=m, weights=work.Weights(layer, m.d_model * m.vocab
                                                 // 4),
                   peaks={"flops": 197e12, "hbm_bytes": 819e9}, batch=8,

@@ -1,7 +1,9 @@
 """The prepared-weight cache: a hit loads, a changed key misses; and the
 weight bytes the rooflines count are the prepared operands' sizes."""
+import dataclasses
 import os
 import shutil
+import types
 
 import jax
 import jax.numpy as jnp
@@ -26,15 +28,27 @@ def _fake_root(tmp_path):
 
 def test_source_key_covers_config_and_program(tmp_path):
     root, cfg = _fake_root(tmp_path)
-    k0 = weights.source_key(cfg, root)
-    assert weights.source_key(cfg, root) == k0
+    m = model.load(cfg)
+    k0 = weights.source_key(m, cfg, root)
+    assert weights.source_key(m, cfg, root) == k0
     with open(os.path.join(root, "src", "repro", "a.py"), "a") as f:
         f.write("y = 2\n")
-    k1 = weights.source_key(cfg, root)
+    k1 = weights.source_key(m, cfg, root)
     assert k1 != k0
     with open(cfg, "a") as f:
         f.write("\n")
-    assert weights.source_key(cfg, root) not in (k0, k1)
+    k2 = weights.source_key(m, cfg, root)
+    assert k2 not in (k0, k1)
+    # the architecture's module: its leaves, shapes and tree
+    arch = tmp_path / "arch.py"
+    shutil.copy(m.arch.__file__, arch)
+    m2 = dataclasses.replace(m, arch=types.SimpleNamespace(
+        __file__=str(arch)))
+    k3 = weights.source_key(m2, cfg, root)
+    assert weights.source_key(m2, cfg, root) == k3
+    with open(arch, "a") as f:
+        f.write("# the tree changed\n")
+    assert weights.source_key(m2, cfg, root) not in (k0, k1, k2, k3)
 
 
 def test_hit_loads_and_changed_key_misses(tmp_path, monkeypatch):
@@ -67,7 +81,7 @@ def test_leaf_draws_are_seeded_and_independent():
                                   np.asarray(weights.leaf(m, "wq", 1)))
     assert not np.array_equal(np.asarray(a),
                               np.asarray(weights.leaf(m, "wq", 0)))
-    assert a.shape == (m.d_model, m.q_dim) and a.dtype == jnp.float32
+    assert a.shape == (m.d_model, m.arch.q_dim(m)) and a.dtype == jnp.float32
 
 
 @pytest.mark.parametrize("bits", [2, 3])
@@ -94,8 +108,8 @@ def test_kernel_bytes_are_the_prepared_operands(bits):
                                          p.codebooks))
     assert stored == round(p.bits_per_weight() * L * d_in * d_out / 8)
     m = model.load(TINY)
-    params = {"stack": {"attn": {n: p for n in weights.ATTN},
-                        "mlp": {n: p for n in weights.MLP}},
+    params = {"stack": {"attn": {n: p for n in m.arch.ATTN},
+                        "mlp": {n: p for n in m.arch.MLP}},
               "lm_head": p}
     wb = _weight_bytes(model.Model(**{**m.__dict__, "n_layers": L}), params)
     assert wb.layer_bytes["wq"] * L == stored
@@ -112,19 +126,20 @@ def test_kernel_bytes_are_the_prepared_operands(bits):
 
 def test_step_work_counts():
     m = model.load(TINY)
-    wb = work.Weights({n: 100 for n, _, _ in m.layer_linears()}, 50)
+    a = m.arch
+    wb = work.Weights({n: 100 for n, _, _ in a.layer_linears(m)}, 50)
     s = work.Step("decode", [(10, 1), (0, 1)], 0, 2)
-    assert work.step_flops(m, s) == (
-        2 * m.linear_params() * 2 + 2 * m.d_model * m.vocab * 2
-        + work.attn_flops(m, 10, 1) + work.attn_flops(m, 0, 1))
-    assert work.attn_flops(m, 10, 1) == 4 * m.n_layers * m.n_heads \
-        * m.head_dim * 11
-    assert work.step_bytes(m, wb, s) == (
-        m.n_layers * 700 + 50 + (11 + 1) * m.kv_row_bytes()
+    assert a.step_flops(m, s) == (
+        2 * a.linear_params(m) * 2 + 2 * m.d_model * m.vocab * 2
+        + a.attn_flops(m, 10, 1) + a.attn_flops(m, 0, 1))
+    assert a.attn_flops(m, 10, 1) == 4 * m.n_layers * m.block.n_heads \
+        * m.block.head_dim * 11
+    assert a.step_bytes(m, wb, s) == (
+        m.n_layers * 700 + 50 + (11 + 1) * a.kv_row_bytes(m)
         + 2 * m.d_model * 2)
     pk = {"flops": 1e12, "hbm_bytes": 1e9}
     assert work.least_s(m, wb, s, pk) == max(
-        work.step_flops(m, s) / 1e12, work.step_bytes(m, wb, s) / 1e9)
+        a.step_flops(m, s) / 1e12, a.step_bytes(m, wb, s) / 1e9)
 
 
 def test_unknown_device_kind_is_an_error():

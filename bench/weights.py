@@ -6,8 +6,11 @@ float32 from the configuration's ``weight_seed``; the plain reference
 serving program gets them quantized and prepared by its own code
 (``quantize_tree``, ``prepare_serving_params``), once per checkout: the
 prepared tree is written to ``bench/.cache/`` under a key that covers the
-configuration file, this file and every source file of the program, so a
-changed quantizer or layout never reads a stale entry. Later runs load it.
+configuration file, this file, the architecture's module and every source
+file of the program, so a changed quantizer, layout or tree never reads a
+stale entry. Later runs load it. Which leaves there are, their order
+(whose index seeds each draw), their shapes and the tree they make are
+the architecture module's (``bench/archs/``).
 """
 from __future__ import annotations
 
@@ -29,30 +32,8 @@ BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
 CACHE_DIR = os.path.join(BENCH_DIR, ".cache")
 
-# (name, per layer?) in a fixed order: the index seeds the leaf
-LEAVES = (("wq", True), ("wk", True), ("wv", True), ("wo", True),
-          ("w_gate", True), ("w_up", True), ("w_down", True),
-          ("ln1", True), ("ln2", True),
-          ("embed", False), ("final_norm", False), ("lm_head", False))
-_INDEX = {n: i for i, (n, _) in enumerate(LEAVES)}
-NORMS = ("ln1", "ln2", "final_norm")
-ATTN = ("wq", "wk", "wv", "wo")
-MLP = ("w_gate", "w_up", "w_down")
 EMBED_STD = 0.02     # initializer_range of both published configs
 NORM_STD = 0.05      # norm gains 1 + 0.05 N(0, 1), so a dropped gain shows
-
-
-def leaf_shape(m: Model, name: str) -> Tuple[int, ...]:
-    mats = {n: (a, b) for n, a, b in m.layer_linears()}
-    if name in mats:
-        return mats[name]
-    if name in NORMS:
-        return (m.d_model,)
-    if name == "embed":
-        return (m.vocab, m.d_model)
-    if name == "lm_head":
-        return (m.d_model, m.vocab)
-    raise KeyError(name)
 
 
 def _std(name: str, shape) -> float:
@@ -72,10 +53,12 @@ _draw_jit = jax.jit(_draw, static_argnums=(3, 4, 5))
 
 
 def leaf(m: Model, name: str, layer: int = 0) -> jax.Array:
-    """One float32 leaf (layer ``layer`` of a per-layer leaf)."""
-    shape = leaf_shape(m, name)
-    return _draw_jit(m.weight_seed, _INDEX[name], layer, shape,
-                     _std(name, shape), name in NORMS)
+    """One float32 leaf (layer ``layer`` of a per-layer leaf), seeded by
+    its index in the architecture's ``LEAVES``."""
+    index = [n for n, _ in m.arch.LEAVES].index(name)
+    shape = tuple(m.arch.leaf_shape(m, name))
+    return _draw_jit(m.weight_seed, index, layer, shape,
+                     _std(name, shape), name in m.arch.NORMS)
 
 
 def stacked(m: Model, name: str) -> jax.Array:
@@ -97,12 +80,12 @@ def build(m: Model, log: Callable[[str], None] = print):
     times = dict(draw=0.0, quantize=0.0, prepare=0.0)
     out: Dict[str, object] = {}
     bits = n_w = 0.0
-    for name, per_layer in LEAVES:
+    for name, per_layer in m.arch.LEAVES:
         t = time.perf_counter()
         w = stacked(m, name) if per_layer else leaf(m, name)
         jax.block_until_ready(w)
         times["draw"] += time.perf_counter() - t
-        if name in NORMS or name == "embed":
+        if name in m.arch.NORMS or name == "embed":
             out[name] = w.astype(dt)
             continue
         t = time.perf_counter()
@@ -119,12 +102,7 @@ def build(m: Model, log: Callable[[str], None] = print):
         times["prepare"] += time.perf_counter() - t
         out[name] = p[name]
         del q
-    tree = dict(
-        embed=out["embed"], final_norm=out["final_norm"],
-        lm_head=out["lm_head"],
-        stack=dict(ln1=out["ln1"], ln2=out["ln2"],
-                   attn={n: out[n] for n in ATTN},
-                   mlp={n: out[n] for n in MLP}))
+    tree = m.arch.tree(out)
     log(f"weights built: storage {bits / max(n_w, 1):.4f} bits/weight over "
         f"{n_w / 1e6:.1f}M quantized weights; draw {times['draw']:.1f} s, "
         f"quantize {times['quantize']:.1f} s, prepare "
@@ -132,11 +110,20 @@ def build(m: Model, log: Callable[[str], None] = print):
     return tree, times
 
 
-def source_key(config_file: str, root: str = ROOT) -> str:
+def prepared_bytes(leaf) -> int:
+    """Bytes of a prepared leaf as it sits in HBM, without the selector
+    memo that only the XLA arm reads."""
+    total = sum(int(a.nbytes) for a in jax.tree.leaves(leaf))
+    memo = getattr(leaf, "sel_memo", None)
+    return total - (int(memo.nbytes) if memo is not None else 0)
+
+
+def source_key(m: Model, config_file: str, root: str = ROOT) -> str:
     """Hash of what the prepared tree depends on: the configuration file,
-    this file, the program's sources, JAX's version and the platform."""
+    this file, the architecture's module, the program's sources, JAX's
+    version and the platform."""
     h = hashlib.sha256()
-    files = [config_file, os.path.abspath(__file__)]
+    files = [config_file, os.path.abspath(__file__), m.arch.__file__]
     files += sorted(glob.glob(os.path.join(root, "src", "repro", "**",
                                            "*.py"), recursive=True))
     for f in files:
@@ -156,7 +143,7 @@ def load_or_build(m: Model, config_file: str, cache_dir: str = CACHE_DIR,
                   log: Callable[[str], None] = print,
                   root: str = ROOT) -> Tuple[object, Optional[dict]]:
     """(prepared tree on the device, build seconds or None on a hit)."""
-    path = cache_file(m, source_key(config_file, root), cache_dir)
+    path = cache_file(m, source_key(m, config_file, root), cache_dir)
     if os.path.exists(path):
         with open(path, "rb") as f:
             host = pickle.load(f)

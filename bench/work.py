@@ -1,5 +1,6 @@
 """What a launch of the serving step costs at the least: model operations
-and bytes, counted from the shapes, and the chip's least time for them.
+and bytes, counted from the shapes by the architecture's module
+(``bench/archs/``), and the chip's least time for them.
 
 A ``Step`` is one launch the window made, as the harness saw it: its
 program (``decode`` or ``fused``) and, for each live lane, the KV rows
@@ -43,29 +44,12 @@ def peaks(device_kind: str) -> Dict[str, float]:
     return table[device_kind]
 
 
-def attn_flops(m: Model, ctx: int, n: int) -> int:
-    """QK^T and PV over every layer for n new tokens after ctx rows."""
-    keys = n * ctx + n * (n + 1) // 2
-    return 4 * m.n_layers * m.n_heads * m.head_dim * keys
-
-
-def step_flops(m: Model, s: Step) -> int:
-    toks = sum(n for _, n in s.lanes)
-    lin = 2 * m.linear_params() * toks
-    head = 2 * m.d_model * m.vocab * len(s.lanes)
-    return lin + head + sum(attn_flops(m, c, n) for c, n in s.lanes)
-
-
-def step_bytes(m: Model, w: Weights, s: Step) -> int:
-    weights = m.n_layers * sum(w.layer_bytes.values()) + w.lm_head_bytes
-    kv = sum((c + n) * m.kv_row_bytes() for c, n in s.lanes)
-    embed = sum(n for _, n in s.lanes) * m.d_model * m.itemsize
-    return weights + kv + embed
-
-
 def least_s(m: Model, w: Weights, s: Step, pk: Dict[str, float]) -> float:
-    return max(step_flops(m, s) / pk["flops"],
-               step_bytes(m, w, s) / pk["hbm_bytes"])
+    """The larger of the launch's model operations over peak FLOP/s and
+    its bytes over peak bandwidth, as the architecture's module counts
+    them (``step_flops``, ``step_bytes``)."""
+    return max(m.arch.step_flops(m, s) / pk["flops"],
+               m.arch.step_bytes(m, w, s) / pk["hbm_bytes"])
 
 
 def mfu(w) -> Optional[float]:
