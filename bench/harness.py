@@ -7,7 +7,9 @@ public hooks only: ``submit``; ``Request.on_token``, which stamps every
 token on the host's monotonic clock; ``on_iteration``, called at the top
 of every engine iteration, which refills the closed loop, opens and
 closes the window, and records each launch (its program from the
-engine's step counters, its lanes from the requests in flight); and
+engine's step counters, its lanes from the requests in flight, and its
+change in each engine metric the architecture's ``STEP_COUNTERS``
+names); and
 ``cancel``, for the requests still open when the window closes.
 
 A run: weights (from the checkout's cache after the first run), engine,
@@ -25,7 +27,7 @@ import os
 import re
 import sys
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -83,8 +85,10 @@ class Driver:
     def __init__(self, eng, tr: traffic.Traffic, spec: traffic.Spec,
                  seconds: float, trace_dir: Optional[str],
                  compiles: Callable[[], int] = lambda: 0,
-                 clock: Callable[[], float] = time.monotonic):
+                 clock: Callable[[], float] = time.monotonic,
+                 step_counters: Sequence[str] = ()):
         self.eng, self.tr, self.spec = eng, tr, spec
+        self.step_counters = tuple(step_counters)
         self.seconds = seconds
         self.trace_dir = trace_dir
         self.clock = clock
@@ -139,13 +143,18 @@ class Driver:
         return (m.fused_steps, m.decode_steps,
                 m.prefill_tokens + m.prompt_decode_tokens, self.emitted)
 
+    def _counts(self) -> List[np.ndarray]:
+        """The engine metrics the architecture names for each launch."""
+        return [np.array(getattr(self.eng.metrics, k))
+                for k in self.step_counters]
+
     def _account(self) -> None:
         """Turn the launch the last iteration made into a ``Step``, and
         check the lanes the harness expected against the counters."""
         snap = self._snap()
         if self.plan is None:
             return
-        prev, lanes = self.plan
+        prev, counts, lanes = self.plan
         self.plan = None
         d_fused, d_dec = snap[0] - prev[0], snap[1] - prev[1]
         if d_fused + d_dec == 0:
@@ -173,7 +182,9 @@ class Driver:
             self.steps.append(work.Step(
                 "fused" if d_fused else "decode",
                 [(c, n) for (_, c, _), n in zip(lanes, ns)],
-                seen[2], seen[3]))
+                seen[2], seen[3],
+                {k: (b - a).tolist() for k, a, b in zip(
+                    self.step_counters, counts, self._counts())}))
 
     def on_iteration(self) -> None:
         if self._iter_span is not None:
@@ -199,7 +210,7 @@ class Driver:
             if (self.t_ready is not None
                     and now - self.t_ready >= self.spec.preroll_s):
                 self._open(now)
-        self.plan = (self._snap(), [
+        self.plan = (self._snap(), self._counts(), [
             (req.rid, self.consumed[req.rid], len(req.prompt))
             for req in self.cur.values() if req.status is None])
         if self.phase == "window" and self.trace_dir is not None:
@@ -328,7 +339,8 @@ def run(config_file: str, traffic_file: str, e2e: Dict[str, str],
 
         shutil.rmtree(tdir, ignore_errors=True)
     drv = Driver(eng, tr, spec, seconds, tdir,
-                 compiles=lambda: _COMPILES[0])
+                 compiles=lambda: _COMPILES[0],
+                 step_counters=getattr(m.arch, "STEP_COUNTERS", ()))
     drv.drive()
     setup_s = drv.t_open - t_start
     T = drv.t_close - drv.t_open

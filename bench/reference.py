@@ -133,7 +133,12 @@ def served(m: Model, name: str, layer_i: int = 0):
 
 
 def quantized(m: Model, name: str, layer_i: int = 0):
-    return icq_rtn(weights.leaf(m, name, layer_i), m.bits, m.gamma)
+    """A matrix through the reference's ICQuant RTN; an expert stack
+    (E, d_in, d_out) one 2-D slice at a time."""
+    w = weights.leaf(m, name, layer_i)
+    if w.ndim == 2:
+        return icq_rtn(w, m.bits, m.gamma)
+    return jnp.stack([icq_rtn(s, m.bits, m.gamma) for s in w])
 
 
 def logits(m: Model, seqs: Sequence[Tuple[np.ndarray, int]], pad_to: int,

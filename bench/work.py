@@ -3,8 +3,11 @@ and bytes, counted from the shapes by the architecture's module
 (``bench/archs/``), and the chip's least time for them.
 
 A ``Step`` is one launch the window made, as the harness saw it: its
-program (``decode`` or ``fused``) and, for each live lane, the KV rows
-it already held and the tokens it fed. ``Weights`` holds the bytes of the
+program (``decode`` or ``fused``), for each live lane the KV rows it
+already held and the tokens it fed, and the launch's change in each
+engine metric that the architecture's module names in its optional
+``STEP_COUNTERS`` (say, the experts a launch routed to in each layer,
+whose weights alone it read). ``Weights`` holds the bytes of the
 prepared quantized matrices as they sit in HBM.
 """
 from __future__ import annotations
@@ -26,6 +29,7 @@ class Step:
     lanes: List[Tuple[int, int]]       # (KV rows held before, tokens fed)
     prompt_tokens: int
     out_tokens: int
+    counters: Dict[str, object] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
